@@ -53,9 +53,8 @@ def _xla_bytes(fn, *args):
     reports nothing).  The empirical cross-check on the analytic
     ``hbm_bytes_model``: same dataflow, counted by the compiler instead
     of by hand."""
-    from repro.utils.jaxcompat import cost_analysis_dict
     compiled = jax.jit(fn).lower(*args).compile()
-    val = cost_analysis_dict(compiled).get("bytes accessed")
+    val = (compiled.cost_analysis() or {}).get("bytes accessed")
     return None if val is None else int(val)
 
 

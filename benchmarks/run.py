@@ -35,6 +35,8 @@ def main() -> None:
                          "Monte-Carlo throughput)")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks.common import BenchScale
     from repro.obs.manifest import build_manifest
     scale = BenchScale.full() if args.full else BenchScale()

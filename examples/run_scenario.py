@@ -135,6 +135,8 @@ def main() -> None:
                          "CI/chaos testing)")
     args = ap.parse_args()
 
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.core import TopologyConfig, make_topology
     from repro.data import (SyntheticImageConfig, make_synthetic_images,
                             partition_iid)
@@ -363,6 +365,11 @@ def main() -> None:
         with open(args.out, "w") as f:
             json.dump(payload, f, indent=2)
         print(f"  wrote {args.out}")
+    if stream is not None and stream.errors:
+        # The tap swallows host-side errors so the running computation
+        # survives them; the run still failed, so the exit code says so.
+        raise SystemExit(f"stream tap recorded {len(stream.errors)} "
+                         f"error(s), first: {stream.errors[0]}")
 
 
 if __name__ == "__main__":

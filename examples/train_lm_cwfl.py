@@ -21,6 +21,7 @@ from repro.models.config import ArchConfig, InputShape, LayerSpec
 from repro.data import make_token_dataset
 from repro.training import dist_steps as ds
 from repro.checkpoint import save_checkpoint
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -34,6 +35,7 @@ def main():
     ap.add_argument("--snr-db", type=float, default=40.0)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.large:   # ~100M params
         cfg = ArchConfig(name="lm-100m", arch_type="dense", num_layers=12,

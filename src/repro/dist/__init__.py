@@ -15,16 +15,6 @@ Three layers, mirroring the paper's offline/online split:
 """
 from __future__ import annotations
 
-import jax
-
-# ``jax.shard_map`` graduated from ``jax.experimental.shard_map`` in newer
-# jax releases; export a version-agnostic binding here (without mutating
-# the jax namespace) and spell it ``repro.dist.shard_map`` everywhere.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-from repro.dist import fl_integration, ota_collectives, sharding_rules  # noqa: E402,F401
-from repro.dist.fl_integration import (FLPlan, hierarchical_ota_allreduce,  # noqa: E402,F401
+from repro.dist import fl_integration, ota_collectives, sharding_rules  # noqa: F401
+from repro.dist.fl_integration import (FLPlan, hierarchical_ota_allreduce,  # noqa: F401
                                        make_fl_plan)

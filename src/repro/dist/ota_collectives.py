@@ -23,11 +23,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import cwfl
 from repro.core.cwfl import CWFLState
-from repro.dist import shard_map
 from repro.dist.fl_integration import FLPlan, hierarchical_ota_allreduce
 from repro.kernels.cwfl_round import PALLAS_MIN_DIM, cwfl_round_auto
 from repro.kernels.ota_aggregate import DEFAULT_TILE

@@ -17,9 +17,10 @@ the ``(K, TILE)`` signal block is read once, and only the final
 never touch HBM (see :func:`hbm_bytes_model` and DESIGN.md §Perf).
 
 TPU-native notes (DESIGN.md §8): all three matmuls ride the MXU via
-``dot_general`` with ``preferred_element_type=f32`` (bf16 signals
-accumulate in f32); tiles are 128-lane aligned; ``d`` is padded to a tile
-multiple internally and the pad sliced off (ragged last tile).  Validated
+``dot_general`` at f32 precision (``SYNC_PRECISION``) with
+``preferred_element_type=f32`` (bf16 signals accumulate in f32); tiles
+are 128-lane aligned; ``d`` is padded to a tile multiple internally and
+the pad sliced off (ragged last tile).  Validated
 in interpret mode against :func:`repro.kernels.ref.cwfl_round_ref`.
 """
 from __future__ import annotations
@@ -31,7 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ota_aggregate import DEFAULT_TILE, resolve_interpret
+from repro.kernels.ota_aggregate import (DEFAULT_TILE, SYNC_PRECISION,
+                                         resolve_interpret)
 
 # Below this flat dimension the round is a handful of tiny matmuls; the
 # jnp reference is a single fused XLA computation and the kernel's tile
@@ -51,13 +53,16 @@ def _cwfl_round_kernel(a_ref, b_ref, m_ref, s_ref, n1_ref, n2_ref,
 
     dims = (((1,), (0,)), ((), ()))
     theta_tilde = jax.lax.dot_general(
-        a, s, dims, preferred_element_type=jnp.float32)
+        a, s, dims, precision=SYNC_PRECISION,
+        preferred_element_type=jnp.float32)
     theta_tilde = theta_tilde + n1_ref[...].astype(jnp.float32)   # (C, T)
     theta_bar = jax.lax.dot_general(
-        b, theta_tilde, dims, preferred_element_type=jnp.float32)
+        b, theta_tilde, dims, precision=SYNC_PRECISION,
+        preferred_element_type=jnp.float32)
     theta_bar = theta_bar + n2_ref[...].astype(jnp.float32)       # (C, T)
     new = jax.lax.dot_general(
-        m, theta_bar, dims, preferred_element_type=jnp.float32)   # (K, T)
+        m, theta_bar, dims, precision=SYNC_PRECISION,
+        preferred_element_type=jnp.float32)                       # (K, T)
     new_ref[...] = new.astype(new_ref.dtype)
     cons_ref[...] = jnp.mean(theta_bar, axis=0, keepdims=True)
 
@@ -139,15 +144,18 @@ def _cwfl_round_kernel_guard(a_ref, b_ref, m_ref, s_ref, n1_ref, n2_ref,
 
     dims = (((1,), (0,)), ((), ()))
     theta_tilde = jax.lax.dot_general(
-        a, s, dims, preferred_element_type=jnp.float32)
+        a, s, dims, precision=SYNC_PRECISION,
+        preferred_element_type=jnp.float32)
     theta_tilde = theta_tilde + n1_ref[...].astype(jnp.float32)   # (C, T)
     dead = jnp.sum(jnp.abs(a), axis=1, keepdims=True) <= 0.0
     theta_tilde = jnp.where(dead, 0.0, theta_tilde)
     theta_bar = jax.lax.dot_general(
-        b, theta_tilde, dims, preferred_element_type=jnp.float32)
+        b, theta_tilde, dims, precision=SYNC_PRECISION,
+        preferred_element_type=jnp.float32)
     theta_bar = theta_bar + n2_ref[...].astype(jnp.float32)       # (C, T)
     new = jax.lax.dot_general(
-        m, theta_bar, dims, preferred_element_type=jnp.float32)   # (K, T)
+        m, theta_bar, dims, precision=SYNC_PRECISION,
+        preferred_element_type=jnp.float32)                       # (K, T)
     new_ref[...] = new.astype(new_ref.dtype)
     cons_ref[...] = jnp.mean(theta_bar, axis=0, keepdims=True)
 

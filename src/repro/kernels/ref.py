@@ -4,6 +4,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ota_aggregate import SYNC_PRECISION
+
+
+def _mm(a, b):
+    """f32 matmul at the sync's precision on every backend."""
+    return jnp.matmul(a, b, precision=SYNC_PRECISION)
+
 
 def ota_aggregate_ref(signals: jnp.ndarray, weights: jnp.ndarray,
                       noise: jnp.ndarray) -> jnp.ndarray:
@@ -14,7 +21,7 @@ def ota_aggregate_ref(signals: jnp.ndarray, weights: jnp.ndarray,
     noise:   (C, d) receiver AWGN (pre-generated; the MAC adds it).
     Returns: (C, d) received aggregates  y = W @ S + N.
     """
-    return (weights.astype(jnp.float32) @ signals.astype(jnp.float32)
+    return (_mm(weights.astype(jnp.float32), signals.astype(jnp.float32))
             + noise.astype(jnp.float32)).astype(signals.dtype)
 
 
@@ -42,13 +49,13 @@ def cwfl_round_ref(signals: jnp.ndarray, phase1: jnp.ndarray,
     a = phase1.astype(jnp.float32)
     if guard:
         s = jnp.where(jnp.isfinite(s), s, 0.0)
-    theta_tilde = a @ s + noise1.astype(jnp.float32)
+    theta_tilde = _mm(a, s) + noise1.astype(jnp.float32)
     if guard:
         dead = jnp.sum(jnp.abs(a), axis=1, keepdims=True) <= 0.0
         theta_tilde = jnp.where(dead, 0.0, theta_tilde)
-    theta_bar = (phase2.astype(jnp.float32) @ theta_tilde
+    theta_bar = (_mm(phase2.astype(jnp.float32), theta_tilde)
                  + noise2.astype(jnp.float32))
-    new = (broadcast.astype(jnp.float32) @ theta_bar).astype(signals.dtype)
+    new = _mm(broadcast.astype(jnp.float32), theta_bar).astype(signals.dtype)
     return new, jnp.mean(theta_bar, axis=0)
 
 

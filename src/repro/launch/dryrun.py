@@ -31,7 +31,6 @@ from repro.configs import ARCH_NAMES, get_config
 from repro.launch.mesh import make_production_mesh
 from repro.models.config import INPUT_SHAPES, ArchConfig, InputShape
 from repro.training import dist_steps as ds
-from repro.utils import cost_analysis_dict
 
 
 # ---------------------------------------------------------------------------
@@ -207,7 +206,7 @@ def run_one(arch: str, shape_name: str, mesh, mesh_name: str,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
             ma = compiled.memory_analysis()
-            ca = cost_analysis_dict(compiled)
+            ca = compiled.cost_analysis() or {}
             hlo = compiled.as_text()
             colls = parse_collectives(hlo)
         rec.update({

@@ -31,11 +31,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Small mesh over whatever devices exist (tests: 8 fake CPU devices)."""
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.6 explicit-axes API
-        return jax.make_mesh(
-            (data, model), ("data", "model"),
-            axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _make_1d_mesh(axis: str, num_devices=None):
@@ -44,10 +41,8 @@ def _make_1d_mesh(axis: str, num_devices=None):
         raise ValueError(
             f"requested {n} devices for axis {axis!r}, have "
             f"{len(jax.devices())}")
-    if hasattr(jax.sharding, "AxisType"):  # jax >= 0.6 explicit-axes API
-        return jax.make_mesh((n,), (axis,),
-                             axis_types=(jax.sharding.AxisType.Auto,))
-    return jax.make_mesh((n,), (axis,))
+    return jax.make_mesh((n,), (axis,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def make_mc_mesh(num_devices=None):

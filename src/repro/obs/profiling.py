@@ -22,10 +22,13 @@ from typing import Optional
 class PhaseTimers:
     """Accumulating named wall timers: ``with timers.phase("execute"):``.
     Re-entering a phase accumulates (loop-mode rounds sum into one
-    ``execute`` figure)."""
+    ``execute`` figure).  ``executables`` keeps each program the engine
+    compiled under ``trace_compile``, in order, so a caller can read its
+    HLO (``as_text()``) or ``memory_analysis()``."""
 
     def __init__(self):
         self.seconds: dict[str, float] = {}
+        self.executables: list = []
 
     @contextlib.contextmanager
     def phase(self, name: str):

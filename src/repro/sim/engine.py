@@ -639,6 +639,7 @@ def run_rounds(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
         elif timers is not None:
             with timers.phase("trace_compile"):
                 fn = fn.lower(carry, scan_xs).compile()
+            timers.executables.append(fn)
             with timers.phase("execute"):
                 carry, out = jax.block_until_ready(fn(carry, scan_xs))
         else:
@@ -758,6 +759,7 @@ def run_monte_carlo(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
             return fn(*a)
         with timers.phase("trace_compile"):
             fn = fn.lower(*a).compile()
+        timers.executables.append(fn)
         with timers.phase("execute"):
             return jax.block_until_ready(fn(*a))
 

@@ -38,11 +38,12 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import cwfl
-from repro.dist import shard_map
 from repro.dist.sharding_rules import client_specs, trajectory_specs
+from repro.kernels.ota_aggregate import SYNC_PRECISION
 from repro.launch.mesh import make_client_mesh, make_mc_mesh
 from repro.models.small import accuracy as _accuracy
 from repro.obs.telemetry import RoundTelemetry, init_ledger, per_client_dim
@@ -86,8 +87,8 @@ def make_sharded_sweep_fn(traj, n_pad: int, rounds: int, mesh,
     out_spec = trajectory_specs(
         jax.ShapeDtypeStruct((n_pad, rounds), jnp.float32), mesh)
 
-    # check_rep=False: the body is collective-free (rep checking has
-    # nothing to verify) and the fused CWFL pallas_call has no
+    # check_vma=False: the body is collective-free (replication checking
+    # has nothing to verify) and the fused CWFL pallas_call has no
     # replication rule.
     if with_grid:
         body = lambda s, g: jax.vmap(traj)(s, g)
@@ -109,7 +110,7 @@ def make_sharded_sweep_fn(traj, n_pad: int, rounds: int, mesh,
         out_specs = (out_spec, out_spec)
     return jax.jit(shard_map(
         body, mesh=mesh, in_specs=in_specs,
-        out_specs=out_specs, check_rep=False))
+        out_specs=out_specs, check_vma=False))
 
 
 def monte_carlo_sharded(traj, seeds: jnp.ndarray, snr_grid, snr_db,
@@ -251,18 +252,22 @@ def _client_sharded_sync(stacked_local, state, key: jax.Array, axis: str,
     r = jax.lax.axis_index(axis)
     a_loc = jax.lax.dynamic_slice_in_dim(A, r * kl, kl, axis=1)   # (C, K')
 
+    # f32 matmuls on every backend, as in the fused kernel.
+    mm = lambda x, y: jnp.matmul(x, y, precision=SYNC_PRECISION)
+
     # Phase 1 (eq. 8): the OTA MAC — per-cluster sums over all K clients
     # ride the mesh collective; receiver AWGN is shared-key replicated.
-    theta_tilde = jax.lax.psum(a_loc @ flat, axis)                # (C, d)
+    theta_tilde = jax.lax.psum(mm(a_loc, flat), axis)             # (C, d)
     theta_tilde = theta_tilde + cwfl._flat_leaf_noise(
         k1, leaves, C, eff_std1)
 
     # Phase 2 (eq. 9 / lemma 2): tiny (C, C) mix, rank-local.
-    theta_bar = B @ theta_tilde + cwfl._flat_leaf_noise(k2, leaves, C, kappa)
+    theta_bar = mm(B, theta_tilde) + cwfl._flat_leaf_noise(k2, leaves, C,
+                                                          kappa)
 
     # Phase 3: error-free downlink — this rank's clients only.
     m_loc = jax.lax.dynamic_slice_in_dim(m_back, r * kl, kl, axis=0)
-    new_flat = m_loc @ theta_bar                                  # (K', d)
+    new_flat = mm(m_loc, theta_bar)                               # (K', d)
     cons_flat = jnp.mean(theta_bar, axis=0)                       # (d,)
     new, cons = cwfl._flat_unpack(new_flat, cons_flat, leaves, treedef, kl)
     if not with_telemetry:
@@ -512,7 +517,7 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
         traj, mesh=mesh,
         in_specs=in_specs,
         out_specs=out_specs,
-        check_rep=False)   # scan+psum bodies defeat the rep checker
+        check_vma=False)   # scan+psum bodies defeat the replication checker
     fj = jax.jit(f)
 
     tele = None

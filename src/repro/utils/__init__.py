@@ -1,4 +1,3 @@
-from repro.utils.jaxcompat import cost_analysis_dict
 from repro.utils.pytree import (
     tree_add,
     tree_scale,

@@ -23,8 +23,7 @@ SCRIPT = textwrap.dedent("""
     mesh = make_local_mesh(4, 2)
 
     def flops(c):
-        from repro.utils import cost_analysis_dict
-        return cost_analysis_dict(c).get("flops", 0.0)
+        return c.cost_analysis().get("flops", 0.0)
 
     out = {}
     for arch in %(archs)s:

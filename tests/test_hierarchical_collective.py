@@ -17,7 +17,7 @@ SCRIPT = textwrap.dedent("""
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro.dist import shard_map
+    from jax import shard_map
     from repro.dist.fl_integration import (make_fl_plan,
                                            hierarchical_ota_allreduce)
     from repro.launch.mesh import make_local_mesh
@@ -87,8 +87,7 @@ REPLICA_SCRIPT = textwrap.dedent("""
                                               local_steps=2)
     with mesh:
         c = jax.jit(fn, in_shardings=ds.sr.named(sh, mesh)).lower(*args).compile()
-    from repro.utils import cost_analysis_dict
-    ca = cost_analysis_dict(c)
+    ca = c.cost_analysis()
     print("RESULT::" + json.dumps(
         {"flops": ca.get("flops", 0.0),
          "collectives": sum(1 for l in c.as_text().splitlines()

@@ -10,6 +10,12 @@ within 2 ulp at T=2 (in practice bitwise for most strategies — COTAF's
 precode chain is the one observed to re-fuse), accuracy histories
 bitwise, shapes/grids identical.
 """
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -232,3 +238,75 @@ def test_mesh_device_cap_errors():
     from repro.launch.mesh import make_mc_mesh
     with pytest.raises(ValueError, match="devices"):
         make_mc_mesh(len(jax.devices()) + 1)
+
+
+# ---------------------------------------------------------------------------
+# Both executors on four virtual devices, whatever this process has.
+# ---------------------------------------------------------------------------
+
+FOUR_DEVICE_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import json
+import jax
+import numpy as np
+from repro.core import TopologyConfig, make_topology
+from repro.data import SyntheticImageConfig, make_synthetic_images, partition_iid
+from repro.launch.mesh import make_client_mesh, make_mc_mesh
+from repro.models import make_mnist_mlp, nll_loss
+from repro.sim import run_monte_carlo, run_rounds
+from repro.training import FLConfig
+
+K = 8
+dcfg = SyntheticImageConfig.mnist_like(num_train=960, num_test=256)
+(xtr, ytr), (xte, yte) = make_synthetic_images(jax.random.PRNGKey(0), dcfg)
+topo = make_topology(jax.random.PRNGKey(7),
+                     TopologyConfig(num_clients=K, num_hotspots=3))
+xs, ys = partition_iid(jax.random.PRNGKey(1), xtr, ytr, K)
+init, apply = make_mnist_mlp(hidden=(32,))
+loss = lambda p, x, y: nll_loss(apply(p, x), y)
+args = (init, apply, loss, topo, xs, ys, xte, yte,
+        FLConfig(strategy="cwfl", rounds=2, eval_samples=256, seed=0))
+
+mc_mesh, cl_mesh = make_mc_mesh(), make_client_mesh()
+h_s = run_monte_carlo(*args, seeds=4, shard="mc", mesh=mc_mesh)
+h_v = run_monte_carlo(*args, seeds=4)
+h_c = run_rounds(*args, shard="clients", mesh=cl_mesh)
+h_u = run_rounds(*args)
+diff = lambda a, b: float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
+print("RESULT::" + json.dumps({
+    "devices": len(jax.devices()),
+    "mesh_sizes": [int(mc_mesh.devices.size), int(cl_mesh.devices.size)],
+    "mc_spans": len(h_s["train_loss"].sharding.device_set),
+    "clients_spans": min(len(x.sharding.device_set)
+                         for x in jax.tree.leaves(h_c["final_params"])),
+    "mc_shape": list(np.shape(h_s["train_loss"])),
+    "mc_loss": diff(h_s["train_loss"], h_v["train_loss"]),
+    "mc_acc": diff(h_s["test_acc"], h_v["test_acc"]),
+    "clients_loss_rel": diff(h_c["train_loss"], h_u["train_loss"])
+                        / float(np.max(np.abs(np.asarray(h_u["train_loss"])))),
+    "clients_acc": diff(h_c["test_acc"], h_u["test_acc"]),
+}))
+"""
+
+
+def test_sharded_executors_on_four_virtual_devices():
+    """The mc-sharded sweep and the client-sharded trajectory run across
+    a 4-device mesh and agree with their single-device runs (the mesh is
+    the whole device set, and results span it) — in a subprocess that
+    sets its own device count, so this runs on any host."""
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    proc = subprocess.run(
+        [sys.executable, "-c", FOUR_DEVICE_SCRIPT], capture_output=True,
+        text=True, timeout=300,
+        env={**os.environ, "PYTHONPATH": src, "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT::")]
+    out = json.loads(line[0][len("RESULT::"):])
+    assert out["devices"] == 4 and out["mesh_sizes"] == [4, 4]
+    assert out["mc_spans"] == 4 and out["clients_spans"] == 4
+    assert out["mc_shape"] == [4, 2]
+    # seeds-only sweeps are bitwise (see the parity contract above);
+    # the client psum re-associates the OTA sums (ulp-level)
+    assert out["mc_loss"] == 0.0 and out["mc_acc"] == 0.0
+    assert out["clients_loss_rel"] <= 1e-5 and out["clients_acc"] <= 1e-2

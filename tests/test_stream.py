@@ -21,6 +21,7 @@ The load-bearing contracts, in order of blast radius:
   healthy runs, and ``abort_on_alert`` checkpoint-then-stops a run that
   remains resumable.
 """
+import importlib.util
 import json
 import os
 import re
@@ -33,6 +34,7 @@ import numpy as np
 import pytest
 
 from goldens.generate import STRATEGIES, workload
+import repro.obs
 from repro.core import TopologyConfig
 from repro.obs import (ConsensusDriftRule, ConvergenceStallRule,
                        JsonlStreamSink, MemorySink, Monitor,
@@ -429,3 +431,39 @@ def test_watch_run_renders_and_gates(tmp_path):
                         "--fail-on-alert"], capture_output=True, text=True)
     assert r.returncode == 2
     assert "nonfinite_loss" in r.stdout
+
+
+@pytest.mark.parametrize("sink_fails", [False, True])
+def test_run_scenario_exit_code_reflects_tap_errors(tmp_path, monkeypatch,
+                                                    sink_fails):
+    """The tap swallows host-side errors into ``stream.errors`` so the
+    scan survives them; ``examples/run_scenario.py`` must still exit
+    non-zero when any were recorded, and 0 when none were."""
+    class FailingSink(JsonlStreamSink):
+        def write(self, record):
+            if record.get("type") == "stream":
+                raise OSError("sink refused the record")
+            super().write(record)
+
+    if sink_fails:
+        monkeypatch.setattr(repro.obs, "JsonlStreamSink", FailingSink)
+    # A set variable leaves the process's compile cache as it is.
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    script = os.path.join(os.path.dirname(__file__), "..", "examples",
+                          "run_scenario.py")
+    spec = importlib.util.spec_from_file_location("run_scenario", script)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    monkeypatch.setattr(sys, "argv", [
+        "run_scenario.py", "--clients", "4", "--rounds", "2", "--hidden",
+        "8", "--train", "400", "--test", "64",
+        "--stream", str(tmp_path / "live.jsonl")])
+    if sink_fails:
+        with pytest.raises(SystemExit) as e:
+            mod.main()
+        assert e.value.code not in (0, None)
+        assert "sink refused the record" in str(e.value.code)
+    else:
+        mod.main()
+        lines = (tmp_path / "live.jsonl").read_text().splitlines()
+        assert [json.loads(l)["type"] for l in lines].count("stream") == 2
