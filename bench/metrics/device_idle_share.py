@@ -1,0 +1,9 @@
+"""device_idle_share: share of the traced window in which no operation ran
+on the device, in %: 1 − (union of the device-op intervals ÷ window),
+averaged over the chips used."""
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return 100.0 * (1.0 - run.trace.mean_busy_s() / run.trace.window_s)
