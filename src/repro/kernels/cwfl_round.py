@@ -120,6 +120,9 @@ def cwfl_round(signals: jnp.ndarray, phase1: jnp.ndarray,
             jax.ShapeDtypeStruct((1, dp), jnp.float32),
         ],
         interpret=interpret,
+        # The compiled custom call (and its events in a device trace) is
+        # named after this: ``cwfl_round.<n>`` under any scope or wrapper.
+        name="cwfl_round",
     )(phase1.astype(jnp.float32), phase2.astype(jnp.float32),
       broadcast.astype(jnp.float32), signals, noise1.astype(jnp.float32),
       noise2.astype(jnp.float32))

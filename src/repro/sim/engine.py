@@ -45,6 +45,7 @@ import numpy as np
 from repro.core import channel as ch
 from repro.core.topology import Topology, TopologyConfig
 from repro.models.small import accuracy as _accuracy
+from repro.obs.profiling import SCOPE_EVAL, SCOPE_LOCAL, SCOPE_SYNC
 from repro.obs.telemetry import build_round_telemetry, init_ledger
 from repro.optim import sgd
 from repro.sim.faults import init_faults, quarantine_mask, step_faults
@@ -333,18 +334,21 @@ def _build(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
             carry = dict(carry)
             k_local, k_agg = jax.random.split(inp["rkey"])
             client_keys = jax.random.split(k_local, K)
-            trained, opt_state, losses = jax.vmap(local_run)(
-                carry["stacked"], carry["opt"], xs, ys, client_keys)
-            if static:
-                stacked, consensus = strategy.aggregate(trained, state0,
-                                                        k_agg)
-                state, mask, reclustered, fault_extras = (state0, None,
-                                                          None, None)
-            else:
-                (stacked, consensus, state, mask, reclustered,
-                 fault_extras) = dynamic_sync(carry, trained, inp, k_agg)
-            logits = apply_fn(consensus, x_ev)
-            acc = _accuracy(logits, y_ev)
+            with jax.named_scope(SCOPE_LOCAL):
+                trained, opt_state, losses = jax.vmap(local_run)(
+                    carry["stacked"], carry["opt"], xs, ys, client_keys)
+            with jax.named_scope(SCOPE_SYNC):
+                if static:
+                    stacked, consensus = strategy.aggregate(trained, state0,
+                                                            k_agg)
+                    state, mask, reclustered, fault_extras = (state0, None,
+                                                              None, None)
+                else:
+                    (stacked, consensus, state, mask, reclustered,
+                     fault_extras) = dynamic_sync(carry, trained, inp, k_agg)
+            with jax.named_scope(SCOPE_EVAL):
+                logits = apply_fn(consensus, x_ev)
+                acc = _accuracy(logits, y_ev)
             carry.update(stacked=stacked, opt=opt_state, consensus=consensus)
             if not telemetry:
                 return carry, (jnp.mean(losses), acc)
@@ -622,7 +626,12 @@ def run_rounds(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
     # legacy loop had (offline setup + init op-by-op, rounds compiled), so
     # the scanned trajectory stays bit-identical to it; only Monte-Carlo
     # sweeps trace `prepare` (under vmap over seeds/scenario scalars).
-    ctx, carry, scan_xs = prepare(cfg.seed, cfg.snr_db)
+    if timers is None:
+        ctx, carry, scan_xs = prepare(cfg.seed, cfg.snr_db)
+    else:
+        with timers.phase("prepare"):
+            ctx, carry, scan_xs = jax.block_until_ready(
+                prepare(cfg.seed, cfg.snr_db))
     body = make_body(ctx)
 
     tele = None
@@ -639,7 +648,7 @@ def run_rounds(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
         elif timers is not None:
             with timers.phase("trace_compile"):
                 fn = fn.lower(carry, scan_xs).compile()
-            timers.executables.append(fn)
+            timers.compiled(fn)
             with timers.phase("execute"):
                 carry, out = jax.block_until_ready(fn(carry, scan_xs))
         else:
@@ -759,7 +768,7 @@ def run_monte_carlo(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
             return fn(*a)
         with timers.phase("trace_compile"):
             fn = fn.lower(*a).compile()
-        timers.executables.append(fn)
+        timers.compiled(fn)
         with timers.phase("execute"):
             return jax.block_until_ready(fn(*a))
 

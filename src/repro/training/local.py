@@ -12,6 +12,8 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from repro.obs.profiling import SCOPE_BATCH
+
 
 def fedprox_wrap(loss_fn: Callable, mu_prox: float) -> Callable:
     """loss(params, x, y) -> loss + (µ_p/2)·‖params − global‖² (paper §V)."""
@@ -49,11 +51,13 @@ def make_local_runner(loss_fn: Callable, optimizer, batch_size: int,
 
         def step(carry, k):
             p, s = carry
-            idx = jax.random.randint(k, (batch_size,), 0, x.shape[0])
+            with jax.named_scope(SCOPE_BATCH):
+                idx = jax.random.randint(k, (batch_size,), 0, x.shape[0])
+                xb, yb = x[idx], y[idx]
             if prox:
-                loss, grads = grad_fn(p, x[idx], y[idx], global_params)
+                loss, grads = grad_fn(p, xb, yb, global_params)
             else:
-                loss, grads = grad_fn(p, x[idx], y[idx])
+                loss, grads = grad_fn(p, xb, yb)
             updates, s = optimizer.update(grads, s, p)
             p = jax.tree.map(jnp.add, p, updates)
             return (p, s), loss

@@ -314,6 +314,124 @@ def test_phase_timers_accumulate():
 
 
 # ---------------------------------------------------------------------------
+# Phases are spans on the profiler's clock; the round's layers are named
+# scopes that survive into the compiled program's op metadata.
+# ---------------------------------------------------------------------------
+
+def _hlo_ops(text: str) -> list[tuple[str, str]]:
+    """(instruction name, opcode) of every instruction of HLO text."""
+    import re
+    pat = re.compile(r"^\s*(?:ROOT )?%([^\s=]+) = \S+ ([\w-]+)\(")
+    return [m.groups() for m in map(pat.match, text.splitlines()) if m]
+
+
+def test_phase_spans_share_the_profiler_clock(tmp_path):
+    import glob
+    import time
+
+    from jax.profiler import ProfileData
+    t = PhaseTimers()
+    with jax.profiler.trace(str(tmp_path)):
+        with t.phase("outer"):
+            time.sleep(0.002)
+            with t.phase("inner"):
+                jnp.ones(8).block_until_ready()
+                time.sleep(0.002)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    profile = ProfileData.from_file(path)
+    # Host events are stamped relative to the capture's start, which the
+    # trace records on the wall clock in ns.
+    (start,) = [v for p in profile.planes for k, v in p.stats
+                if k == "profile_start_time"]
+    events = {ev.name: ev for p in profile.planes if p.name.startswith("/host")
+              for line in p.lines for ev in line.events
+              if ev.name.startswith("repro.")}
+    assert [(n, parent) for n, parent, _, _ in t.spans] == [
+        ("inner", "outer"), ("outer", None)]
+    for name, _, s_ns, e_ns in t.spans:
+        ev = events[f"repro.{name}"]
+        assert abs(start + ev.start_ns - s_ns) < 100_000
+        assert abs(start + ev.end_ns - e_ns) < 100_000
+        assert e_ns > s_ns
+    assert t.seconds["outer"] >= t.seconds["inner"] > 0
+
+
+def test_run_rounds_records_prepare_phase(wl):
+    t = PhaseTimers()
+    _run(wl, _cfg("cwfl"), timers=t)
+    names = [n for n, _, _, _ in t.spans]
+    assert names == ["prepare", "trace_compile", "execute"]
+    assert all(parent is None for _, parent, _, _ in t.spans)
+    assert t.seconds["prepare"] > 0
+    assert len(t.executables) == 1
+
+
+def test_run_monte_carlo_has_no_prepare_phase(wl):
+    from repro.obs.profiling import ROUND_SCOPES, op_scopes
+    init, apply, loss, topo, xs, ys, xte, yte = wl
+    t = PhaseTimers()
+    run_monte_carlo(init, apply, loss, topo, xs, ys, xte, yte, _cfg("cwfl"),
+                    seeds=2, timers=t)
+    assert "prepare" not in t.seconds      # traced into the program
+    assert set(op_scopes().values()) == set(ROUND_SCOPES)
+
+
+def test_op_scopes_of_compiled_round():
+    """The scopes of a small CPU-compiled round (MLP, K = 4): the
+    minibatch gather under ``fl_batch``, a matmul of the local step under
+    ``fl_local``, the sync's ops under ``fl_sync``, the eval's matmul under
+    ``fl_eval``; innermost wins."""
+    from repro.core import make_topology
+    from repro.data import (SyntheticImageConfig, make_synthetic_images,
+                            partition_iid)
+    from repro.models import make_mnist_mlp, nll_loss
+    from repro.obs.profiling import op_scopes
+
+    k = 4
+    (xtr, ytr), (xte, yte) = make_synthetic_images(
+        jax.random.PRNGKey(0),
+        SyntheticImageConfig.mnist_like(num_train=512, num_test=128))
+    topo = make_topology(jax.random.PRNGKey(7),
+                         TopologyConfig(num_clients=k, num_hotspots=2))
+    xs, ys = partition_iid(jax.random.PRNGKey(1), xtr, ytr, k)
+    init, apply = make_mnist_mlp(hidden=(32,))
+    loss = lambda p, x, y: nll_loss(apply(p, x), y)
+    t = PhaseTimers()
+    run_rounds(init, apply, loss, topo, xs, ys, xte, yte,
+               _cfg("cwfl", num_clusters=2, eval_samples=128, batch_size=32),
+               timers=t)
+    scopes = op_scopes()
+    ops = _hlo_ops(t.executables[-1].as_text())
+    by_op = {}
+    for name, opcode in ops:
+        by_op.setdefault(opcode, set()).add(scopes.get(name))
+    assert "fl_batch" in by_op["gather"]
+    assert {"fl_local", "fl_eval"} <= by_op["dot"]
+    assert "fl_sync" in set(scopes.values())
+    # A matmul is never put down to the minibatch draw.
+    assert "fl_batch" not in by_op["dot"]
+    # Ops outside the round body (the scan's own bookkeeping) map to nothing.
+    assert any(name not in scopes for name, _ in ops)
+
+
+@pytest.mark.parametrize("op_name,scope", [
+    ("jit(f)/while/body/fl_local/vmap()/while/body/fl_batch/gather",
+     "fl_batch"),
+    ("jit(f)/closed_call/fl_local/vmap()/transpose(jvp(jit(relu)))/max",
+     "fl_local"),
+    ("jit(f)/closed_call/fl_sync/jit(cwfl_round)/cwfl_round/pallas_call",
+     "fl_sync"),
+    ("transpose(jvp(fl_eval))/dot_general", "fl_eval"),
+    ("jit(f)/fl_localize/add", None),
+    ("jit(f)/my_fl_sync/add", None),
+    ("", None),
+])
+def test_scope_of_op_name(op_name, scope):
+    from repro.obs.profiling import scope_of
+    assert scope_of(op_name) == scope
+
+
+# ---------------------------------------------------------------------------
 # Device-parallel paths carry telemetry too.
 # ---------------------------------------------------------------------------
 

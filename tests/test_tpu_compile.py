@@ -77,3 +77,54 @@ def test_flash_attention_compiles_for_v5e(one_chip):
     fn = partial(flash_attention, interpret=False)
     qkv = ((1, 8, 1024, 128), jnp.bfloat16)
     assert "tpu_custom_call" in _compiled_text(fn, one_chip, qkv, qkv, qkv)
+
+
+def test_scoped_round_keeps_kernel_name_for_v5e(one_chip, monkeypatch):
+    """The whole scoped MNIST-shaped round (K=4, C=2, one hidden layer)
+    compiled for a v5e: the fused sync's custom call keeps the name the
+    trace metrics match (``cwfl_round.<n>``) and lies under ``fl_sync``."""
+    import re
+
+    import repro.kernels.cwfl_round as kernel
+    from repro.core import TopologyConfig, make_topology
+    from repro.data import (SyntheticImageConfig, make_synthetic_images,
+                            partition_iid)
+    from repro.models import make_mnist_mlp, nll_loss
+    from repro.obs.profiling import hlo_op_scopes
+    from repro.sim import get_scenario
+    from repro.sim.engine import _SCAN_UNROLL, _build
+    from repro.training import FLConfig
+
+    k = 4
+    (xtr, ytr), (xte, yte) = make_synthetic_images(
+        jax.random.PRNGKey(0),
+        SyntheticImageConfig.mnist_like(num_train=512, num_test=128))
+    tcfg = TopologyConfig(num_clients=k, num_hotspots=2)
+    topo = make_topology(jax.random.PRNGKey(7), tcfg)
+    xs, ys = partition_iid(jax.random.PRNGKey(1), xtr, ytr, k)
+    init, apply = make_mnist_mlp(hidden=(32,))
+    loss = lambda p, x, y: nll_loss(apply(p, x), y)
+    cfg = FLConfig(strategy="cwfl", rounds=2, batch_size=64, num_clusters=2,
+                   snr_db=40.0, eval_samples=128, seed=0)
+    prepare, make_body = _build(init, apply, loss, topo, xs, ys, xte, yte,
+                                cfg, get_scenario("paper-static"), tcfg)
+    ctx, carry, scan_xs = prepare(cfg.seed, cfg.snr_db)
+    body = make_body(ctx)
+    # The CPU backend would pick interpret mode; compile the kernel itself.
+    monkeypatch.setattr(kernel, "resolve_interpret",
+                        lambda i: False if i is None else i)
+    jax.clear_caches()
+    try:
+        fn = jax.jit(lambda c, x: jax.lax.scan(body, c, x,
+                                               unroll=_SCAN_UNROLL))
+        spec = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                              sharding=one_chip)
+        text = fn.lower(jax.tree.map(spec, carry),
+                        jax.tree.map(spec, scan_xs)).compile().as_text()
+    finally:
+        jax.clear_caches()
+    assert "tpu_custom_call" in text
+    scopes = hlo_op_scopes(text)
+    kernels = [n for n in re.findall(r"%([\w.-]+) = ", text)
+               if re.match(r"cwfl_round(\.\d+)?$", n)]
+    assert kernels and all(scopes.get(n) == "fl_sync" for n in kernels)
