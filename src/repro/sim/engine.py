@@ -72,9 +72,21 @@ _SIM_SALT = 0x51B
 _SCAN_UNROLL = 2
 
 
-def make_round_local_runner(loss_fn: Callable, cfg: FLConfig, n_k: int):
+def client_rows(xs) -> tuple[jnp.ndarray, tuple[int, ...]]:
+    """``(K, n_k, *sample)`` client shards as ``(K, n_k, F)`` rows, and the
+    sample shape.  Call it on the concrete data, outside any trace, so
+    the shards the round program embeds are row-major and the minibatch
+    draw is a row gather (`repro.training.local.make_local_runner`'s
+    layout contract); reshaped inside a trace, the layout of the
+    embedded shards is left to XLA's constant folding."""
+    return jnp.reshape(xs, tuple(xs.shape[:2]) + (-1,)), tuple(xs.shape[2:])
+
+
+def make_round_local_runner(loss_fn: Callable, cfg: FLConfig, n_k: int,
+                            sample_shape: tuple[int, ...]):
     """The per-round local-training runner exactly as the engine builds
-    it: E epochs of minibatch SGD over a client's ``n_k`` examples.
+    it: E epochs of minibatch SGD over a client's ``n_k`` examples, held
+    as flat rows of samples shaped ``sample_shape`` (`client_rows`).
     Returns ``(optimizer, local_run)``; `repro.sim.sharded` reuses this
     so the sharded trajectory can never drift from the engine's step
     budget or optimizer construction.
@@ -88,7 +100,7 @@ def make_round_local_runner(loss_fn: Callable, cfg: FLConfig, n_k: int):
     steps_per_round = max(cfg.local_epochs * (n_k // cfg.batch_size), 1)
     return optimizer, make_local_runner(
         loss_fn, optimizer, cfg.batch_size, steps_per_round,
-        strategy.effective_mu_prox(cfg.mu_prox))
+        strategy.effective_mu_prox(cfg.mu_prox), sample_shape=sample_shape)
 
 
 def _tree_where(mask: jnp.ndarray, a, b):
@@ -143,6 +155,7 @@ def _build(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
             UserWarning, stacklevel=3)
 
     K, n_k = xs.shape[0], xs.shape[1]
+    xs, sample_shape = client_rows(xs)
     static = scenario.is_static
     dyn_chan = scenario.channel.evolves_geometry  # CSI-only needs no geometry
     masked = not scenario.schedule.is_trivial
@@ -156,7 +169,8 @@ def _build(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
             "generated the topology (geometry statics: area, d0, ς, "
             "outage threshold)")
 
-    optimizer, local_run = make_round_local_runner(loss_fn, cfg, n_k)
+    optimizer, local_run = make_round_local_runner(loss_fn, cfg, n_k,
+                                                   sample_shape)
     x_ev = x_test[: cfg.eval_samples]
     y_ev = y_test[: cfg.eval_samples]
 
@@ -361,7 +375,8 @@ def _build(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
             # already materialized (it feeds the sync), so reading it is
             # bit-neutral.  Full-batch per-client loss is also the better
             # observable: deterministic, minibatch-noise-free.
-            tele_losses = jax.vmap(loss_fn)(trained, xs, ys)
+            tele_losses = jax.vmap(loss_fn)(
+                trained, xs.reshape((K, n_k) + sample_shape), ys)
             tele, carry["obs"] = build_round_telemetry(
                 strategy, state, losses=tele_losses, stacked=trained,
                 new_stacked=stacked, consensus=consensus, mask=mask,

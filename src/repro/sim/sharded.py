@@ -47,7 +47,8 @@ from repro.kernels.ota_aggregate import SYNC_PRECISION
 from repro.launch.mesh import make_client_mesh, make_mc_mesh
 from repro.models.small import accuracy as _accuracy
 from repro.obs.telemetry import RoundTelemetry, init_ledger, per_client_dim
-from repro.sim.engine import _SCAN_UNROLL, make_round_local_runner
+from repro.sim.engine import (_SCAN_UNROLL, client_rows,
+                              make_round_local_runner)
 from repro.sim.scenarios import Scenario
 from repro.strategies import get_strategy
 from repro.training.federated import FLConfig
@@ -392,7 +393,8 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
     params0 = carry0["consensus"]
     round_keys = scan_xs["rkey"]
 
-    _, local_run = make_round_local_runner(loss_fn, cfg, n_k)
+    xs, sample_shape = client_rows(xs)
+    _, local_run = make_round_local_runner(loss_fn, cfg, n_k, sample_shape)
     x_ev = x_test[: cfg.eval_samples]
     y_ev = y_test[: cfg.eval_samples]
 
@@ -442,7 +444,8 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
             # minibatch `losses` again would re-fuse its psum-mean and
             # perturb the reported train_loss by ulps (same contract as
             # the unsharded engine body).
-            tele_losses = jax.vmap(loss_fn)(st, xs_l, ys_l)
+            tele_losses = jax.vmap(loss_fn)(
+                st, xs_l.reshape(xs_l.shape[:2] + sample_shape), ys_l)
             cluster_loss = jax.lax.psum(mem_loc @ tele_losses,
                                         "clients") / counts
             d = per_client_dim(st)

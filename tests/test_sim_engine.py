@@ -228,3 +228,86 @@ def test_unknown_strategy_raises(setup):
     with pytest.raises(KeyError, match="unknown strategy"):
         run_rounds(init, apply, loss, topo, xs, ys, xte, yte,
                    FLConfig(strategy="nope", rounds=1))
+
+
+# ---------------------------------------------------------------------------
+# Row-major client shards: the draw gathers flat rows, bit for bit x[idx].
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("sample", [(28, 28, 1), (32, 32, 3), (10,)])
+def test_row_draw_matches_sample_shaped_gather(sample):
+    """`client_rows` + `draw_minibatch` (the engine's draw, vmapped over
+    clients under jit) give bit for bit the minibatch that ``x[idx]``
+    gives on the sample-shaped shard under the same key."""
+    from repro.sim.engine import client_rows
+    from repro.training.local import draw_minibatch
+
+    k, n, b = 3, 96, 16
+    xs = jax.random.normal(jax.random.PRNGKey(0), (k, n) + sample)
+    ys = jax.random.randint(jax.random.PRNGKey(1), (k, n), 0, 10)
+    keys = jax.random.split(jax.random.PRNGKey(2), k)
+    rows, shape = client_rows(xs)
+    assert rows.shape == (k, n, int(np.prod(sample))) and shape == sample
+    xb, yb = jax.jit(jax.vmap(
+        lambda x, y, key: draw_minibatch(x, y, key, b, shape)))(rows, ys, keys)
+    for c in range(k):
+        idx = jax.random.randint(keys[c], (b,), 0, n)
+        want = np.asarray(xs[c][idx])
+        assert xb[c].shape == want.shape
+        np.testing.assert_array_equal(np.asarray(xb[c]).view(np.uint32),
+                                      want.view(np.uint32))
+        np.testing.assert_array_equal(np.asarray(yb[c]),
+                                      np.asarray(ys[c][idx]))
+
+
+def _sample_shaped_runner(loss_fn, optimizer, batch_size, local_steps,
+                          mu_prox=0.0, sample_shape=None):
+    """The local step as it was with sample-shaped shards: ``x[idx]`` on
+    ``(n_k, *sample)`` (no FedProx: the runs below use plain CWFL)."""
+    assert mu_prox == 0.0
+    grad_fn = jax.value_and_grad(loss_fn)
+
+    def run(params, opt_state, x, y, key):
+        def step(carry, k):
+            p, s = carry
+            idx = jax.random.randint(k, (batch_size,), 0, x.shape[0])
+            loss, grads = grad_fn(p, x[idx], y[idx])
+            updates, s = optimizer.update(grads, s, p)
+            return (jax.tree.map(jnp.add, p, updates), s), loss
+
+        (params, opt_state), losses = jax.lax.scan(
+            step, (params, opt_state), jax.random.split(key, local_steps))
+        return params, opt_state, jnp.mean(losses)
+
+    return run
+
+
+@pytest.mark.parametrize("model", ["mlp", "cnn"])
+def test_row_shards_replay_sample_shaped_round_bitwise(model, monkeypatch):
+    """A short `run_rounds` on row-major shards gives the same
+    train_loss / test_acc bits as the engine built with sample-shaped
+    shards and the old ``x[idx]`` step."""
+    from repro.models import make_cifar_cnn
+    from repro.sim import engine
+
+    if model == "mlp":
+        dcfg = SyntheticImageConfig.mnist_like(num_train=512, num_test=128)
+        init, apply = make_mnist_mlp(hidden=(32,))
+    else:
+        dcfg = SyntheticImageConfig("cifar-like", 8, 8, 3, 10, 512, 128)
+        init, apply = make_cifar_cnn(input_hw=(8, 8, 3))
+    (xtr, ytr), (xte, yte) = make_synthetic_images(jax.random.PRNGKey(0),
+                                                   dcfg)
+    k = 4
+    topo = make_topology(jax.random.PRNGKey(7),
+                         TopologyConfig(num_clients=k, num_hotspots=2))
+    xs, ys = partition_iid(jax.random.PRNGKey(1), xtr, ytr, k)
+    loss = lambda p, x, y: nll_loss(apply(p, x), y)
+    cfg = FLConfig(strategy="cwfl", rounds=3, batch_size=32, num_clusters=2,
+                   snr_db=40.0, eval_samples=128, seed=5)
+    h_rows = run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg)
+    monkeypatch.setattr(engine, "client_rows",
+                        lambda x: (x, tuple(x.shape[2:])))
+    monkeypatch.setattr(engine, "make_local_runner", _sample_shaped_runner)
+    h_old = run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg)
+    assert _hist_equal(h_rows, h_old)
