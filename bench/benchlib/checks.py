@@ -9,7 +9,8 @@ Numbers, each held to its limit in the configuration's ``check.limits``:
   rounds of rounding have compounded: it sees a changed step that the
   whole trajectory's spread would hide;
 * ``acc_gap``   — worst gap of a round's test accuracy (a share of the
-  test set), over the same rounds;
+  test set's target positions: its samples, or every token of its
+  sequences), over the same rounds;
 * ``state_gap`` — for each leaf of the consensus and of the per-client params,
   the gap between the norms of the program's and the reference's change
   over the call, |‖Δθ_prog‖ − ‖Δθ_ref‖|, over the larger of the

@@ -1,10 +1,17 @@
-"""A configuration's inputs from its seeds: MNIST-/CIFAR-shaped images, the
-paper's label-sorted non-IID split and the wireless topology, all made on
-the device in one jitted call.
+"""A configuration's inputs from its seeds: MNIST-/CIFAR-shaped images or
+token sequences, the paper's label-sorted non-IID split and the wireless
+topology, all made on the device in one jitted call.
 
-Copies of the generators in ``repro.data.synthetic`` and
-``repro.core.topology`` (same draws, same key schedule), kept here so that
-no change to the program can move the inputs it is measured on.
+Copies of the generators in ``repro.data.synthetic``,
+``repro.data.tokens`` (extended to topics) and ``repro.core.topology``
+(same draws, same key schedule), kept here so that no change to the
+program can move the inputs it is measured on.
+
+``data.kind`` picks the samples: ``mnist-like`` and ``cifar-like`` images
+with a class label each, or ``tokens``, sequences whose targets are the
+next tokens.  A token sequence's topic plays the label's part in the
+split, as the speaker or user does in LEAF's Shakespeare and Reddit sets
+(arXiv 1812.01097).
 """
 from __future__ import annotations
 
@@ -36,6 +43,39 @@ def _images(key, d):
 
     return sample(k_ytr, k_ntr, d["num_train"]), sample(k_yte, k_nte,
                                                          d["num_test"])
+
+
+def _tokens(key, d):
+    """Sequences of ``seq_len + 1`` tokens over ``vocab_size`` ids, each from
+    a first-order Markov chain of its topic: ``num_topics`` successor
+    tables of ``branching`` successors per token, and at each step a
+    ``reset_p`` chance of a uniform token instead.  Topics are drawn
+    uniformly; the test set is drawn the same way.  Returns
+    ``(train sequences, topics), (test sequences, topics)``."""
+    V, S, B = d["vocab_size"], d["seq_len"], d["branching"]
+    k_table, k_tr, k_te = jax.random.split(key, 3)
+    tables = jax.random.randint(k_table, (d["num_topics"], V, B), 0, V)
+
+    def sample(k, n):
+        k_topic, k_start, k_choice, k_reset, k_resetv = jax.random.split(k, 5)
+        topic = jax.random.randint(k_topic, (n,), 0, d["num_topics"])
+        starts = jax.random.randint(k_start, (n,), 0, V)
+        choices = jax.random.randint(k_choice, (n, S), 0, B)
+        resets = jax.random.bernoulli(k_reset, d["reset_p"], (n, S))
+        reset_vals = jax.random.randint(k_resetv, (n, S), 0, V)
+
+        def gen(t, s, ch, rs, rv):
+            def step(tok, inp):
+                choice, reset, r = inp
+                nxt = jnp.where(reset, r, tables[t, tok, choice])
+                return nxt, nxt
+            _, seq = jax.lax.scan(step, s, (ch, rs, rv))
+            return jnp.concatenate([s[None], seq])
+
+        seqs = jax.vmap(gen)(topic, starts, choices, resets, reset_vals)
+        return seqs.astype(jnp.int32), topic
+
+    return sample(k_tr, d["num_train"]), sample(k_te, d["num_test"])
 
 
 def _noniid(key, x, y, clients, per_client, num_shards):
@@ -81,10 +121,22 @@ def _topology(key, t):
 def _make(spec: str):
     conf = json.loads(spec)
     d, t = conf["data"], conf["topology"]
-    (xtr, ytr), (xte, yte) = _images(jax.random.PRNGKey(d["seed"]), d)
-    xs, ys = _noniid(jax.random.PRNGKey(d["seed"] + 1), xtr, ytr,
-                     t["num_clients"], d["shards_per_client"],
-                     d["num_shards"])
+
+    def split(x, y):
+        return _noniid(jax.random.PRNGKey(d["seed"] + 1), x, y,
+                       t["num_clients"], d["shards_per_client"],
+                       d["num_shards"])
+
+    if d["kind"] == "tokens":
+        (seq, topic), (seq_te, _) = _tokens(jax.random.PRNGKey(d["seed"]), d)
+        seqs, _ = split(seq, topic)
+        xs, ys = seqs[..., :-1], seqs[..., 1:]
+        xte, yte = seq_te[:, :-1], seq_te[:, 1:]
+    elif d["kind"] in ("mnist-like", "cifar-like"):
+        (xtr, ytr), (xte, yte) = _images(jax.random.PRNGKey(d["seed"]), d)
+        xs, ys = split(xtr, ytr)
+    else:
+        raise ValueError(f"unknown data kind {d['kind']!r}")
     positions, link_gain, snr, adjacency = _topology(
         jax.random.PRNGKey(t["seed"]), t)
     return {"xs": xs, "ys": ys, "xte": xte, "yte": yte,
@@ -94,7 +146,9 @@ def _make(spec: str):
 
 def make_inputs(conf: dict) -> dict:
     """Device arrays of the configuration's deployment: client shards
-    ``xs``/``ys`` (K, n_k, ...), the test set, and the topology."""
+    ``xs``/``ys`` (K, n_k, ...), the test set, and the topology.  For
+    ``tokens`` the inputs and targets are int32 ``(..., seq_len)``, the
+    targets the inputs shifted by one."""
     spec = json.dumps({"data": conf["data"], "topology": conf["topology"]},
                       sort_keys=True)
     return jax.block_until_ready(_make(spec))

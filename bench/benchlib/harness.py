@@ -74,6 +74,23 @@ def _reports(metric: dict, workload: str) -> bool:
     return "workloads" not in metric or workload in metric["workloads"]
 
 
+def config_cell(name: str, conf_path: Path, traffic: str,
+                chips: int = 1) -> Cell:
+    """The cell ``name``: the configuration at ``conf_path`` (its ``.json``,
+    with its ``.py`` beside it) under the mix ``bench/traffic/<traffic>.json``,
+    reporting the metrics that ``BENCHMARK.json`` asks of it.  The
+    configuration need not be in ``BENCHMARK.json``: such a cell can be
+    rehearsed through ``run_cell`` before it is a cell of the benchmark."""
+    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+    return Cell(
+        name=name, chips=chips, conf=json.loads(conf_path.read_text()),
+        model=load_module(conf_path.with_suffix(".py")),
+        traffic=json.loads(
+            (BENCH / "traffic" / f"{traffic}.json").read_text()),
+        end_to_end=[m for m in spec["end_to_end"] if _reports(m, name)],
+        per_layer=[m for m in spec["per_layer"] if _reports(m, name)])
+
+
 def load_cell(workload: str) -> Cell:
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
@@ -82,15 +99,8 @@ def load_cell(workload: str) -> Cell:
                          f"choose from {sorted(cells)}")
     cell = cells[workload]
     config = {c["name"]: c for c in spec["configs"]}[cell["config"]]
-    conf_path = ROOT / config["file"]
-    return Cell(
-        name=workload, chips=int(cell["chips"]),
-        conf=json.loads(conf_path.read_text()),
-        model=load_module(conf_path.with_suffix(".py")),
-        traffic=json.loads(
-            (BENCH / "traffic" / f"{cell['traffic']}.json").read_text()),
-        end_to_end=[m for m in spec["end_to_end"] if _reports(m, workload)],
-        per_layer=[m for m in spec["per_layer"] if _reports(m, workload)])
+    return config_cell(workload, ROOT / config["file"], cell["traffic"],
+                       int(cell["chips"]))
 
 
 def rehearsal_conf(conf: dict) -> dict:
@@ -124,10 +134,16 @@ def peaks_for(kind: str) -> dict:
 def sizes(conf: dict, model, inputs: dict) -> dict:
     """The round's shapes and its model FLOPs: local training (forward and
     backward of every minibatch), the eval's forward pass, and the sync's
-    three matmuls (2CKd + 2C²d + 2KCd) counted once."""
+    three matmuls (2CKd + 2C²d + 2KCd) counted once.  A sample is what
+    ``model.sample_flops`` counts: an image, or a whole token sequence.
+
+    ``d`` counts the params that ``model.reference_init`` returns: those
+    that are trained and synced.  Weights that a configuration's apply
+    closes over, drawn from a fixed key as the clustering is, are neither
+    trained nor synced, and are not in ``d``."""
     import jax
     fl = conf["fl"]
-    K, n_k = inputs["ys"].shape
+    K, n_k = inputs["ys"].shape[:2]
     C = fl["num_clusters"]
     shapes = jax.eval_shape(model.reference_init, jax.random.PRNGKey(0))
     d = sum(math.prod(x.shape) for x in jax.tree.leaves(shapes))
@@ -228,14 +244,23 @@ def _device_record(jax, devices, chips: int) -> dict:
 
 def run(workload: str, seed: int, seconds: float, trace: bool,
         rehearse: bool, t_start: float) -> int:
-    """One run of ``workload``; the process's exit code."""
+    """One run of the ``BENCHMARK.json`` cell ``workload``; the process's
+    exit code."""
+    return run_cell(load_cell(workload), seed, seconds, trace, rehearse,
+                    t_start)
+
+
+def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
+             rehearse: bool, t_start: float) -> int:
+    """One run of ``cell`` (``load_cell`` or ``config_cell``); the
+    process's exit code."""
     import jax
 
     counter = _CompileCounter()
     jax.monitoring.register_event_listener(counter)
     try:
-        return _run(jax, counter, load_cell(workload), seed, seconds, trace,
-                    rehearse, t_start)
+        return _run(jax, counter, cell, seed, seconds, trace, rehearse,
+                    t_start)
     finally:
         jax.monitoring.unregister_event_listener(counter)
 

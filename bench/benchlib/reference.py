@@ -18,6 +18,14 @@ One round:
             phase 2  θ̄ = B̃·θ̃ + n₂  (head consensus, eq. 9, rows renormalised)
             phase 3  θ_k ← θ̄_c(k)   (downlink),  consensus = mean_c θ̄_c
     eval:   accuracy of the consensus on the test set
+
+A sample is an image with a class label, or a token sequence whose
+targets are its next tokens: the loss and the accuracy are means over
+every target position.  An optional key of the configuration's
+``check``, ``reference_eval_block``, computes the accuracy in blocks of
+that many test samples, summed and then divided by the count, so that
+the eval's logits fit the device at a configuration's own size.  Without
+it the reference evaluates the test set at once.
 """
 from __future__ import annotations
 
@@ -152,16 +160,36 @@ def _sync(stacked, plan, st, total_power, key, dtype, fault=None):
 
 
 def _nll(logp, y):
-    return -jnp.mean(jnp.take_along_axis(logp, y[:, None], axis=1)[:, 0])
+    """Mean NLL over every target position: ``y`` is ``logp``'s shape
+    without its last (class) axis."""
+    return -jnp.mean(jnp.take_along_axis(logp, y[..., None], axis=-1)[..., 0])
+
+
+def _cast(x, dtype):
+    """Floating-point data in the reference's dtype; token ids as they are."""
+    return x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x
+
+
+def _accuracy(apply, params, x, y, block):
+    """Share of target positions whose argmax is the target, over all of
+    ``x`` at once, or summed over blocks of ``block`` samples."""
+    if block is None:
+        return jnp.mean(jnp.argmax(apply(params, x), axis=-1) == y)
+    hits = jax.lax.map(
+        lambda xy: jnp.sum(jnp.argmax(apply(params, xy[0][None]), axis=-1)
+                           == xy[1][None]),
+        (x, y), batch_size=block)
+    return jnp.sum(hits) / y.size
 
 
 # Faults that a run of the program can have, planted here in the reference
 # put in the program's place, to read how far each moves the compared
-# numbers (``bench/calibrate.py``): half of each minibatch left out and
-# the mean taken over the rest; half of the clients (the batch of the
-# round) left out of the sync's sums, the mean taken over the rest; the
-# sync (the exchange between clients) left out; every round's reported
-# accuracy altered by ``ALTERED_ACC``; the call's state returned unchanged.
+# numbers (``bench/calibrate.py``): half of each minibatch (of its images,
+# or of its sequences) left out and the mean taken over the rest; half of
+# the clients (the batch of the round) left out of the sync's sums, the
+# mean taken over the rest; the sync (the exchange between clients) left
+# out; every round's reported accuracy altered by ``ALTERED_ACC``; the
+# call's state returned unchanged.
 FAULTS = ("half_batch", "half_clients", "no_sync", "answer_altered",
           "state_unchanged")
 ALTERED_ACC = 0.05
@@ -169,22 +197,22 @@ ALTERED_ACC = 0.05
 
 @functools.partial(jax.jit, static_argnames=(
     "apply", "C", "batch", "steps", "lr", "total_power", "noise_var",
-    "dtype", "precision", "fault"))
+    "dtype", "precision", "fault", "eval_block"))
 def _trajectory(params0, data, topo, plan_key, round_keys, *, apply, C,
                 batch, steps, lr, total_power, noise_var, dtype, precision,
-                fault):
+                fault, eval_block):
     with jax.default_matmul_precision(precision):
         return _rounds(params0, data, topo, plan_key, round_keys,
                        apply=apply, C=C, batch=batch, steps=steps, lr=lr,
                        total_power=total_power, noise_var=noise_var,
-                       dtype=dtype, fault=fault)
+                       dtype=dtype, fault=fault, eval_block=eval_block)
 
 
 def _rounds(params0, data, topo, plan_key, round_keys, *, apply, C, batch,
-            steps, lr, total_power, noise_var, dtype, fault):
-    xs, ys = data["xs"].astype(dtype), data["ys"]
-    xte, yte = data["xte"].astype(dtype), data["yte"]
-    K, n_k = ys.shape
+            steps, lr, total_power, noise_var, dtype, fault, eval_block):
+    xs, ys = _cast(data["xs"], dtype), data["ys"]
+    xte, yte = _cast(data["xte"], dtype), data["yte"]
+    K, n_k = ys.shape[:2]
     plan = cluster_plan(topo["link_snr"], topo["adjacency"], C, plan_key)
     st = sync_state(plan, topo["link_gain"], total_power, noise_var)
     loss_grad = jax.value_and_grad(
@@ -213,7 +241,7 @@ def _rounds(params0, data, topo, plan_key, round_keys, *, apply, C, batch,
         else:
             stacked, cons = _sync(trained, plan, st, total_power, k_agg,
                                   dtype, fault)
-        acc = jnp.mean(jnp.argmax(apply(cons, xte), axis=-1) == yte)
+        acc = _accuracy(apply, cons, xte, yte, eval_block)
         if fault == "answer_altered":
             acc = acc + ALTERED_ACC
         return (stacked, cons), (jnp.mean(losses), acc)
@@ -245,9 +273,10 @@ def trajectory(model, conf: dict, inputs: dict, plan_key, init_key,
     """Run the reference over ``len(round_keys)`` rounds.  Returns, on
     the host, the per-round mean local loss and test accuracy, and the
     consensus and per-client params before (``state0``) and after
-    (``state1``) the rounds."""
+    (``state1``) the rounds.  The configuration's ``check`` may set
+    ``reference_eval_block`` (module docstring)."""
     fl, total_power = conf["fl"], conf["topology"]["total_power"]
-    K, n_k = inputs["ys"].shape
+    n_k = inputs["ys"].shape[1]
     out = _trajectory(
         model.reference_init(init_key),
         {k: inputs[k] for k in ("xs", "ys")} | {
@@ -259,7 +288,8 @@ def trajectory(model, conf: dict, inputs: dict, plan_key, init_key,
         steps=max(fl["local_epochs"] * (n_k // fl["batch_size"]), 1),
         lr=fl["lr"], total_power=float(total_power),
         noise_var=float(total_power / 10.0 ** (fl["snr_db"] / 10.0)),
-        dtype=dtype, precision=precision, fault=fault)
+        dtype=dtype, precision=precision, fault=fault,
+        eval_block=conf["check"].get("reference_eval_block"))
     return jax.device_get(out)
 
 

@@ -16,7 +16,9 @@ In one process, with one compile of the cell's program:
   ``reference.FAULTS`` against the clean reference.
 
 ``--faults`` picks some of them.  ``--rehearse`` runs on the CPU at the
-configuration's reduced size.
+configuration's reduced size.  ``--config FILE --traffic MIX`` reads a
+configuration that ``BENCHMARK.json`` does not name (``harness.config_cell``)
+in place of ``--workload``.
 """
 import time
 
@@ -41,7 +43,10 @@ def seeds(text: str) -> list[int]:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
+    which = ap.add_mutually_exclusive_group(required=True)
+    which.add_argument("--workload")
+    which.add_argument("--config", type=Path)
+    ap.add_argument("--traffic", default="scan")
     ap.add_argument("--program-seeds", type=seeds, default=[])
     ap.add_argument("--control-seeds", type=seeds, default=[])
     ap.add_argument("--fault-seeds", type=seeds, default=[])
@@ -58,7 +63,9 @@ def main() -> int:
 
     from benchlib import checks, gen, reference
 
-    cell = harness.load_cell(args.workload)
+    cell = (harness.config_cell(f"{args.config.stem}.{args.traffic}",
+                                args.config, args.traffic)
+            if args.config else harness.load_cell(args.workload))
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not args.rehearse:
         print(f"no TPU: found {dev.platform}", file=sys.stderr)
@@ -66,7 +73,7 @@ def main() -> int:
     conf = harness.rehearsal_conf(cell.conf) if args.rehearse else cell.conf
     fl = conf["fl"]
     T = fl["rounds"]
-    out = {"workload": args.workload, "device": dev.device_kind,
+    out = {"workload": cell.name, "device": dev.device_kind,
            "platform": dev.platform, "program": [], "control": [],
            "faults": {}, "seconds": {}}
 

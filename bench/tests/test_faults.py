@@ -2,7 +2,10 @@
 
 Each test drives the harness's whole run (``--rehearse``: it skips the
 look for a chip and runs at the configuration's reduced size on the CPU)
-with one fault planted in the program, and sees ``correct`` false:
+with one fault planted in the program, and sees ``correct`` false, in
+every cell of ``BENCHMARK.json`` and in the token fixture
+``fixtures/tiny_lm_k6`` (int32 token sequences, per-token targets, the
+reference's eval computed in blocks), run as a cell of the ``scan`` mix:
 
 * ``state_unchanged`` — every round returns the state it was given;
 * ``half_batch``      — every local step's loss, and so its gradient, is
@@ -76,13 +79,18 @@ def _answer_altered(monkeypatch):
 FAULTS = {"state_unchanged": _state_unchanged, "half_batch": _half_batch,
           "half_clients": _half_clients, "no_sync": _no_sync,
           "answer_altered": _answer_altered}
+FIXTURES = {"tiny_lm_k6.scan": harness.BENCH / "tests" / "fixtures"
+            / "tiny_lm_k6.json"}
 CELLS = [w["name"] for w in json.loads(
-    (harness.ROOT / "BENCHMARK.json").read_text())["workloads"]]
+    (harness.ROOT / "BENCHMARK.json").read_text())["workloads"]] + list(
+        FIXTURES)
 
 
 def rehearse(workload: str, capsys) -> dict:
-    rc = harness.run(workload, seed=2**31 + 7, seconds=0.0, trace=False,
-                     rehearse=True, t_start=0.0)
+    cell = (harness.config_cell(workload, FIXTURES[workload], "scan")
+            if workload in FIXTURES else harness.load_cell(workload))
+    rc = harness.run_cell(cell, seed=2**31 + 7, seconds=0.0, trace=False,
+                          rehearse=True, t_start=0.0)
     err = capsys.readouterr().err
     line = [x for x in err.splitlines() if x.startswith("rehearsal result")]
     result = json.loads(line[-1].split(": ", 1)[1])

@@ -1,6 +1,6 @@
-"""Each configuration's FLOP count from its widths against XLA's count of
-one unrolled round at a small K, and the sync's byte count against the
-program's own model of it."""
+"""Each configuration's FLOP count, and the token fixture's, from its widths
+against XLA's count of one unrolled round at a small K, and the sync's
+byte count against the program's own model of it."""
 import math
 
 import jax
@@ -10,6 +10,22 @@ import pytest
 from benchlib import harness
 
 CONFIGS = ["mnist_mlp_k50", "cifar_cnn_k27"]
+FIXTURES = {"tiny_lm_k6": harness.BENCH / "tests" / "fixtures"
+            / "tiny_lm_k6.py"}
+
+
+def load(name: str):
+    return harness.load_module(FIXTURES.get(
+        name, harness.BENCH / "configs" / f"{name}.py"))
+
+
+def sample_spec(model):
+    """(sample shape, sample dtype, target shape): an image and its label,
+    or a token sequence and its next tokens."""
+    m = model.CONF["model"]
+    if "input_hw" in m:
+        return tuple(m["input_hw"]), jnp.float32, ()
+    return (m["seq_len"],), jnp.int32, (m["seq_len"],)
 
 
 def unrolled_round(model, K: int, steps: int, batch: int, n_eval: int,
@@ -21,7 +37,7 @@ def unrolled_round(model, K: int, steps: int, batch: int, n_eval: int,
     params = init(jax.random.PRNGKey(0))
     leaves, treedef = jax.tree.flatten(params)
     d = sum(x.size for x in leaves)
-    hw = model.CONF["model"]["input_hw"]
+    sample, dtype, target = sample_spec(model)
 
     def round_(stacked, xs, ys, A, B, M, xe, ye):
         for s in range(steps):
@@ -37,20 +53,20 @@ def unrolled_round(model, K: int, steps: int, batch: int, n_eval: int,
     f32 = jnp.float32
     args = (jax.tree.map(lambda x: jax.ShapeDtypeStruct((K,) + x.shape, f32),
                          params),
-            jax.ShapeDtypeStruct((K, steps, batch, *hw), f32),
-            jax.ShapeDtypeStruct((K, steps, batch), jnp.int32),
+            jax.ShapeDtypeStruct((K, steps, batch, *sample), dtype),
+            jax.ShapeDtypeStruct((K, steps, batch, *target), jnp.int32),
             jax.ShapeDtypeStruct((C, K), f32),
             jax.ShapeDtypeStruct((C, C), f32),
             jax.ShapeDtypeStruct((K, C), f32),
-            jax.ShapeDtypeStruct((n_eval, *hw), f32),
-            jax.ShapeDtypeStruct((n_eval,), jnp.int32))
+            jax.ShapeDtypeStruct((n_eval, *sample), dtype),
+            jax.ShapeDtypeStruct((n_eval, *target), jnp.int32))
     cost = jax.jit(round_).lower(*args).compile().cost_analysis()
     return cost["flops"], d
 
 
-@pytest.mark.parametrize("name", CONFIGS)
+@pytest.mark.parametrize("name", CONFIGS + list(FIXTURES))
 def test_round_flops_match_xla(name):
-    model = harness.load_module(harness.BENCH / "configs" / f"{name}.py")
+    model = load(name)
     K, steps, n_eval, C = 2, 2, 16, 3
     batch = model.CONF["fl"]["batch_size"]
     xla, d = unrolled_round(model, K, steps, batch, n_eval, C)
