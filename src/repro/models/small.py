@@ -34,9 +34,48 @@ def _conv(x, p):
     return y + p["b"]
 
 
-def _maxpool2(x):
-    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                 (1, 2, 2, 1), (1, 2, 2, 1), "VALID")
+def relu_maxpool2(x):
+    """``reduce_window(relu(x), max, 2×2, VALID)`` on NHWC, bit for bit,
+    and its VJP; an odd last row or column is cropped, as VALID does."""
+    _, h, w, _ = x.shape
+    return _relu_maxpool2(x[:, :h // 2 * 2, :w // 2 * 2])
+
+
+def _corners(x):
+    """The four corners of each 2×2 window, in row-major window order,
+    each (B, H/2, W/2, C).  H and W are major dims in the TPU layout, so
+    the reshape is a bitcast and each corner a strided slice of tiles."""
+    b, h, w, c = x.shape
+    xw = x.reshape(b, h // 2, 2, w // 2, 2, c)
+    return [xw[:, :, i, :, j] for i in range(2) for j in range(2)]
+
+
+@jax.custom_vjp
+def _relu_maxpool2(x):
+    return _relu_maxpool2_fwd(x)[0]
+
+
+def _relu_maxpool2_fwd(x):
+    a, b, c, d = _corners(x)
+    m = jnp.maximum(jnp.maximum(a, b), jnp.maximum(c, d))
+    # ReLU is monotone and max exact: pooling first gives the same bits,
+    # with the ReLU on a quarter of the elements.  The residual is the
+    # window position that takes the cotangent: the first maximum in
+    # row-major order, as reduce_window's `ge` select picks it, or 4
+    # (none) where the ReLU is off.
+    first = jnp.where(a == m, 0, jnp.where(b == m, 1, jnp.where(c == m, 2, 3)))
+    return jax.nn.relu(m), jnp.where(m > 0, first, 4).astype(jnp.int8)
+
+
+def _relu_maxpool2_bwd(first, g):
+    b, h, w, c = g.shape
+    window = jnp.arange(4, dtype=jnp.int8).reshape(1, 1, 2, 1, 2, 1)
+    dx = jnp.where(window == first[:, :, None, :, None],
+                   g[:, :, None, :, None], 0)
+    return (dx.reshape(b, 2 * h, 2 * w, c),)
+
+
+_relu_maxpool2.defvjp(_relu_maxpool2_fwd, _relu_maxpool2_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -88,8 +127,7 @@ def make_cifar_cnn(input_hw=(32, 32, 3), num_classes: int = 10):
     def apply(params, x):
         h = x
         for name in ("conv0", "conv1", "conv2"):
-            h = jax.nn.relu(_conv(h, params[name]))
-            h = _maxpool2(h)
+            h = relu_maxpool2(_conv(h, params[name]))
         h = h.reshape(h.shape[0], -1)
         h = jax.nn.relu(h @ params["fc0"]["w"] + params["fc0"]["b"])
         h = h @ params["fc1"]["w"] + params["fc1"]["b"]

@@ -174,3 +174,71 @@ def test_round_draws_rows_without_shard_copy_for_v5e(one_chip, monkeypatch):
              and scopes.get(name) == "fl_batch"
              and re.search(r"calls=%([\w.-]+)", rest).group(1) in gathering]
     assert draws and set(draws) == {(f"{k * b},784", "{1,0")}, draws
+
+
+def test_cnn_step_rounds_pool_cotangent_only_into_convs_for_v5e(one_chip):
+    """The CIFAR CNN's vmapped SGD step at the benchmark cell's shape (27
+    clients × 32 samples of 32×32×3) compiled for a v5e: no select-and-
+    scatter, no reduction over a bfloat16 operand (the bias gradients sum
+    float32), and every reader of a bfloat16 tensor as large as conv0's
+    activation is a bitcast or a fusion that holds a convolution, where
+    the one-pass default rounds operands to bfloat16 anyway."""
+    import math
+    import re
+
+    from repro.models import make_cifar_cnn, nll_loss
+
+    k, b = 27, 32
+    init, apply = make_cifar_cnn()
+
+    def step(params, x, y):
+        def one(p, xb, yb):
+            g = jax.grad(lambda p: nll_loss(apply(p, xb), yb))(p)
+            return jax.tree.map(lambda a, d: a - 1e-3 * d, p, g)
+        return jax.vmap(one)(params, x, y)
+
+    spec = lambda s: jax.ShapeDtypeStruct((k, *s.shape), s.dtype,  # noqa: E731
+                                          sharding=one_chip)
+    params = jax.tree.map(spec, jax.eval_shape(init, jax.random.PRNGKey(0)))
+    text = jax.jit(step).lower(
+        params,
+        jax.ShapeDtypeStruct((k, b, 32, 32, 3), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((k, b), jnp.int32, sharding=one_chip),
+    ).compile().as_text()
+    assert " select-and-scatter(" not in text
+
+    inst = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                      r"([\w\-]+)\(([^)]*)\)(.*)$", re.M)
+    comps = {}
+    for block in text.split("\n\n"):
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) ", block.strip())
+        if head:
+            comps[head.group(1)] = block
+
+    def holds_conv(name):
+        body = comps[name]
+        return "convolution(" in body or any(
+            holds_conv(c) for c in re.findall(r"calls=%([\w.\-]+)", body))
+
+    full = k * b * 32 * 32 * 64
+    sums = ("reduce", "add", "subtract", "multiply", "divide", "dot")
+    for name, block in comps.items():
+        dtype = {m.group(1): m.group(2) for m in inst.finditer(block)}
+        size = {m.group(1): math.prod(int(d) for d in m.group(3).split(",")
+                                      if d) for m in inst.finditer(block)}
+        for m in inst.finditer(block):
+            operands = re.findall(r"%([\w.\-]+)", m.group(5))
+            bf16 = [o for o in operands if dtype.get(o) == "bf16"]
+            if m.group(4) == "reduce":
+                assert not bf16, m.group(0)
+            # a summing fusion with no convolution holds no bfloat16 value
+            if "reduce(" in block and not holds_conv(name):
+                assert m.group(2) != "bf16" and not bf16, m.group(0)
+            big = [o for o in bf16 if size.get(o, 0) >= full]
+            if not big:
+                continue
+            assert m.group(4) not in sums, m.group(0)
+            if block.lstrip().startswith("ENTRY") and m.group(4) != "bitcast":
+                called = re.search(r"calls=%([\w.\-]+)", m.group(6))
+                assert m.group(4) == "fusion" and holds_conv(called.group(1)), \
+                    m.group(0)
