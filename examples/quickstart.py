@@ -44,9 +44,9 @@ def main():
         h = run_federated(
             init, apply, loss, topo, xs, ys, xte, yte,
             FLConfig(strategy=strategy, rounds=12, num_clusters=clusters,
-                     snr_db=40.0, eval_samples=1024),
-            progress=lambda r, l, a: print(
-                f"  round {r:2d}  loss={l:.3f}  acc={a:.3f}"))
+                     snr_db=40.0, eval_samples=1024))
+        for r, l, a in zip(h["round"], h["train_loss"], h["test_acc"]):
+            print(f"  round {r:2d}  loss={l:.3f}  acc={a:.3f}")
         print(f"  final accuracy: {h['final_acc']:.3f}\n")
 
 

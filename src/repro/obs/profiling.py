@@ -92,7 +92,7 @@ class PhaseTimers:
     """Accumulating named phases: ``with timers.phase("execute"):``.
 
     ``seconds`` sums each phase's wall time (re-entering a phase
-    accumulates: loop-mode rounds sum into one ``execute`` figure).
+    accumulates into one figure).
     ``spans`` keeps every entry as ``(name, parent, start_ns, end_ns)``,
     ``parent`` the phase it opened inside (or ``None``), on the clock of
     the profiler's host events; each phase is also a

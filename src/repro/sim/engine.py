@@ -1,13 +1,11 @@
 """The scanned Monte-Carlo round engine (DESIGN.md §Sim).
 
-`run_federated` was a host Python loop: one jitted round, a
-``float(loss)`` device→host sync per round, one seed, one static channel.
-This engine runs the *whole trajectory* as a single ``lax.scan`` — T
+The engine runs the *whole trajectory* as a single ``lax.scan`` — T
 rounds on device, per-round loss/accuracy accumulated in on-device scan
 outputs — and is vmap-able over seeds and scenario scalars, so an
 8-seed × SNR-grid Monte-Carlo sweep compiles to exactly one jit.
 
-Round body (identical math to the pre-engine loop):
+Round body:
 
     local:  E epochs of minibatch SGD per client   (vmap over K)
     sync:   strategy aggregation — CWFL routes through the fused
@@ -26,10 +24,9 @@ Scenario hooks (all `lax.scan`-carried, nothing touches the host):
 * cluster churn          → periodic on-device re-clustering
   (``lax.cond``-gated K-means + head election inside the scan body).
 
-Under the ``paper-static`` scenario the engine reproduces the
-pre-refactor `run_federated` history bit-for-bit (same key schedule, same
-per-round computation; ``mode="loop"`` replays the legacy per-round-jit
-structure for A/B benchmarking and the equivalence test).
+The trajectory's bits under the ``paper-static`` scenario are pinned by
+the committed goldens (``tests/goldens``, replayed by
+``tests/test_goldens.py``).
 """
 from __future__ import annotations
 
@@ -58,17 +55,15 @@ from repro.training.federated import FLConfig
 from repro.training.local import bind_frozen, frozen_base, make_local_runner
 
 # fold_in salt separating the scenario-process key stream (channel, masks,
-# CSI, re-clustering) from the paper's training stream — the static path
-# consumes exactly the pre-engine keys, bit-for-bit.
+# CSI, re-clustering) from the paper's training stream, so adding a
+# scenario process never moves the training keys.
 _SIM_SALT = 0x51B
 
-# lax.scan unroll for the round loop.  At unroll=1 XLA compiles the while-
-# loop body with different elementwise fusion (FMA contraction) than the
-# standalone jitted round, which perturbs the precoded strategies
-# (cwfl/cotaf: the per_client_mean_sq → amplitude-clip chain) by 1 ulp per
-# round; at unroll=2 the loop body fuses identically to the sequential
-# jit and the whole trajectory is bit-identical to the legacy per-round
-# loop (verified for odd/even T in tests/test_sim_engine.py).
+# lax.scan unroll for the round loop.  The chip programs recorded in
+# PERF_LEDGER.jsonl were measured at unroll=2 (the scan body is compiled
+# twice, so every device op of a round appears under two names); changing
+# it changes the compiled programs, and so it is a speed question, not a
+# matter of correctness.
 _SCAN_UNROLL = 2
 
 
@@ -116,32 +111,29 @@ def _build(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
            x_test: jnp.ndarray, y_test: jnp.ndarray, cfg: FLConfig,
            scenario: Scenario, topo_cfg: Optional[TopologyConfig],
            telemetry: bool = False, stream=None):
-    """Returns ``(prepare, body)``: ``prepare(seed, snr_db)`` builds the
-    scan carry + per-round inputs, ``body`` is the round function.  Both
-    are pure jnp — jit them together (scan mode, Monte-Carlo vmap) or
-    run `prepare` eagerly and jit `body` alone (legacy loop mode).
+    """Returns ``(prepare, make_body)``: ``prepare(seed, snr_db) -> (ctx,
+    carry, scan_xs)`` builds the per-trajectory context, the scan carry
+    and the per-round inputs; ``make_body(ctx)`` is the round function
+    over them.  Both are pure jnp: `run_rounds` runs `prepare` eagerly
+    and jits the scan, a Monte-Carlo sweep traces both under vmap.
 
     ``telemetry`` is a STATIC python flag: when False the carry, scan
-    outputs, and every traced op are exactly the untelemetered build —
-    the jaxpr is byte-identical, so the goldens replay bitwise.  When
+    outputs, and every traced op are exactly the untelemetered build.  When
     True the carry grows a cumulative channel-use ledger (``"obs"``) and
     ``body`` emits a third `RoundTelemetry` scan output assembled from
     intermediates the round already computes (`repro.obs.telemetry`).
 
-    ``stream`` (STATIC, requires ``telemetry``) is an optional
-    `repro.obs.stream.RoundStream`: the scan inputs grow an absolute
-    ``(t, seed, snr)`` tag triple and the body ends with one ORDERED
-    `io_callback` draining the round's already-computed metrics +
-    telemetry to the host (`repro.obs.stream.stream_tap`) — no new
-    arithmetic, so streamed metrics stay bitwise.  Unbatched bodies
-    only: Monte-Carlo sweeps must NOT pass ``stream`` here (in-body
-    taps break under vmap) — `run_monte_carlo` wraps the trajectory
-    with the post-scan `stream_trajectory_tap` instead."""
+    ``stream`` (STATIC; callers turn ``telemetry`` on with it,
+    `_run_options`) is an optional `repro.obs.stream.RoundStream`: the
+    scan inputs grow an absolute ``(t, seed, snr)`` tag triple and the
+    body ends with one ORDERED `io_callback` draining the round's
+    already-computed metrics + telemetry to the host
+    (`repro.obs.stream.stream_tap`) — no new arithmetic, so streamed
+    metrics stay bitwise.  Unbatched bodies only: Monte-Carlo sweeps
+    must NOT pass ``stream`` here (in-body taps break under vmap) —
+    `run_monte_carlo` wraps the trajectory with the post-scan
+    `stream_trajectory_tap` instead."""
     strategy = get_strategy(cfg.strategy)
-    if stream is not None and not telemetry:
-        raise ValueError(
-            "stream= drains RoundTelemetry and therefore needs "
-            "telemetry=True (the stream IS the telemetry, live)")
     if scenario.strategy is not None and scenario.strategy != strategy.name:
         # The scenario pins a preferred strategy (resolved by CLIs when no
         # explicit choice is given) but FLConfig.strategy always wins in
@@ -229,11 +221,12 @@ def _build(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
 
     def make_body(ctx):
         """Bind the per-trajectory context (strategy state; + noise var in
-        dynamic mode) into the round body as a CLOSURE, exactly like the
-        legacy ``round_fn``'s jit closure — with eager `prepare` the
-        static-scenario round compiles with the state embedded as
-        constants, which keeps the history bit-identical to the
-        pre-engine loop (argument-vs-constant changes XLA fusion by ulps).
+        dynamic mode) into the round body as a CLOSURE.  With `run_rounds`'
+        eager `prepare` the round compiles with the state embedded as
+        constants: the chip programs recorded in PERF_LEDGER.jsonl were
+        measured that way, and passing the state as an argument instead
+        changes XLA's fusion, so it is a speed question (and moves the
+        goldens by ulps).
         """
         if static:
             state0, nv = ctx, None
@@ -461,19 +454,20 @@ def _run_scan_checkpointed(fn, carry, scan_xs, T: int, directory,
                            resume_step: Optional[int], stop_after:
                            Optional[int], cfg, scenario, strategy_name: str,
                            stream=None):
-    """Drive the scanned trajectory in checkpointed segments.
+    """Drive a scanned trajectory ``fn(carry, scan_xs) -> (carry, out)``
+    in checkpointed segments — the one segment driver of `run_rounds` and
+    `repro.sim.sharded.run_rounds_client_sharded`.
 
     The T-round scan is split at every ``every`` rounds; after each
     segment the FULL carry (param stacks, optimizer + strategy/process
     states, telemetry ledger) and the metrics accumulated so far are
-    persisted via `repro.checkpoint` under ``step_<rounds_done>``.
-    Because the scanned trajectory is bit-identical to the per-round
-    loop over the same body (the unroll-fusion contract pinned in
-    tests/test_sim_engine.py), a chunked scan — and therefore an
-    interrupted-and-resumed trajectory — replays the uninterrupted
-    history BITWISE; `prepare` is eager and deterministic, so the
-    per-round scan inputs regenerate identically on resume and only the
-    carry needs disk.
+    persisted via `repro.checkpoint` under ``step_<rounds_done>``, and
+    the manifest is stamped with ``strategy_name``.  Every segment runs
+    the same compiled scan body on the carry the last one left, and
+    `prepare` is eager and deterministic, so the per-round scan inputs
+    regenerate identically on resume and only the carry needs disk: an
+    interrupted-and-resumed trajectory replays the uninterrupted
+    checkpointed run BITWISE.
 
     Returns ``(carry, out, rounds_done)``; ``rounds_done < T`` only when
     ``stop_after`` deliberately kills the run at a segment boundary (the
@@ -551,179 +545,34 @@ def make_trajectory_fn(prepare: Callable, make_body: Callable) -> Callable:
     return traj
 
 
-def run_rounds(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
-               topology: Topology, xs: jnp.ndarray, ys: jnp.ndarray,
-               x_test: jnp.ndarray, y_test: jnp.ndarray, cfg: FLConfig,
-               scenario: Optional[Scenario] = None,
-               topo_cfg: Optional[TopologyConfig] = None,
-               mode: str = "scan",
-               progress: Optional[Callable] = None,
-               shard: Optional[str] = None,
-               mesh=None,
-               telemetry: bool = False,
-               timers=None,
-               checkpoint_dir: Optional[str] = None,
-               checkpoint_every: int = 0,
-               resume: bool = False,
-               resume_step: Optional[int] = None,
-               stop_after: Optional[int] = None,
-               stream=None) -> dict[str, Any]:
-    """Run one FL trajectory; returns history with on-device arrays.
-
-    ``mode="scan"`` (default): the whole trajectory is one jit — no
-    per-round host sync; metrics come back as (T,) arrays.
-    ``mode="loop"``: the legacy per-round-jit host loop (bit-identical
-    history; supports a live per-round ``progress(r, loss, acc)``
-    callback, and is the baseline the scan speedup is measured against).
-    ``shard="clients"``: distribute the stacked K-client axis over a
-    ``("clients",)`` mesh (`repro.sim.sharded.run_rounds_client_sharded`
-    — local training per rank, the per-cluster OTA sums riding a mesh
-    collective); static CWFL scenarios only.
-    ``telemetry=True`` (static flag, `repro.obs`): record a per-round
-    `RoundTelemetry` under ``history["telemetry"]`` — with the flag off
-    the traced computation is byte-identical to pre-obs builds.
-    ``timers``: an optional `repro.obs.profiling.PhaseTimers` splitting
-    the run into ``trace_compile`` (AOT ``lower().compile()``) and
-    ``execute`` (to ``block_until_ready``) wall phases; ``None`` keeps
-    the default jit path untouched.
-
-    Checkpoint/resume (DESIGN.md §Faults): ``checkpoint_dir`` persists
-    the full scan carry + accumulated metrics every
-    ``checkpoint_every`` rounds (0 ⇒ one final checkpoint) via
-    `repro.checkpoint`, manifest-stamped with the run's config hash;
-    ``resume=True`` restores the latest step (or ``resume_step``) and
-    continues such that the interrupted+resumed history is BITWISE
-    identical to an uninterrupted run.  ``stop_after=r`` deliberately
-    exits at the first segment boundary ≥ r (crash simulation — CI's
-    chaos-smoke).  Scan mode only; ``mode="loop"`` raises.
-
-    ``stream`` (STATIC, needs ``telemetry=True``): a
-    `repro.obs.stream.RoundStream` drained live from inside the scan via
-    an ordered `io_callback` — records arrive on the host in round order
-    while the trajectory runs, metrics stay bitwise, and with
-    ``stream=None`` the traced jaxpr is byte-identical to a
-    streaming-unaware build.  A stream whose monitor escalates alerts to
-    aborts requires ``checkpoint_dir`` (the abort IS a
-    checkpoint-then-stop); scan mode only.
-    """
-    scenario = scenario or Scenario()
-    if checkpoint_dir is None and (resume or stop_after is not None):
-        raise ValueError(
-            "resume/stop_after need checkpoint_dir — there is nothing to "
-            "restore from or checkpoint into")
-    if stream is not None:
-        if not telemetry:
+def _run_options(*, telemetry: bool, stream, checkpoint_dir=None,
+                 resume: bool = False, stop_after: Optional[int] = None,
+                 timers=None) -> bool:
+    """Check the run options `run_rounds`, `run_monte_carlo` and
+    `repro.sim.sharded.run_rounds_client_sharded` share.  Returns whether
+    telemetry is on: a stream drains the round telemetry live, so passing
+    one turns it on."""
+    if checkpoint_dir is None:
+        if resume or stop_after is not None:
             raise ValueError(
-                "stream= drains RoundTelemetry live and needs "
-                "telemetry=True")
-        if mode != "scan":
-            raise ValueError(
-                "stream= taps the scanned trajectory; mode='loop' already "
-                "has a live per-round progress callback")
-        if stream.escalates and checkpoint_dir is None:
+                "resume/stop_after need checkpoint_dir — there is nothing "
+                "to restore from or checkpoint into")
+        if stream is not None and stream.escalates:
             raise ValueError(
                 "abort-on-alert escalates via the checkpoint machinery "
-                "(checkpoint-then-stop, resumable); pass checkpoint_dir")
-    if checkpoint_dir is not None:
-        if mode != "scan":
-            raise ValueError(
-                "checkpointing chunks the scanned trajectory; "
-                "mode='loop' is not supported (and needs no resume — it "
-                "is already a host loop)")
-        if timers is not None:
-            raise ValueError(
-                "timers profile a single-segment run; combine them with "
-                "checkpointing and the phases stop meaning anything")
-    if shard is not None:
-        if shard != "clients":
-            raise ValueError(
-                f"run_rounds shards the client axis only (shard='clients'); "
-                f"got {shard!r} — trajectory sharding (shard='mc') lives in "
-                "run_monte_carlo")
-        if mode != "scan" or progress is not None:
-            raise ValueError(
-                "shard='clients' runs the scanned trajectory only — "
-                "mode='loop' / live progress callbacks are not supported "
-                "on the sharded path")
-        from repro.sim import sharded
-        return sharded.run_rounds_client_sharded(
-            init_fn, apply_fn, loss_fn, topology, xs, ys, x_test, y_test,
-            cfg, scenario=scenario, mesh=mesh, telemetry=telemetry,
-            checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
-            resume=resume, resume_step=resume_step, stop_after=stop_after,
-            stream=stream)
-    prepare, make_body = _build(init_fn, apply_fn, loss_fn, topology, xs, ys,
-                                x_test, y_test, cfg, scenario, topo_cfg,
-                                telemetry=telemetry, stream=stream)
-    T = cfg.rounds
+                "(checkpoint-then-stop, resumable); pass checkpoint_dir "
+                "to a single-trajectory run")
+    elif timers is not None:
+        raise ValueError(
+            "timers profile a single-segment run; combine them with "
+            "checkpointing and the phases stop meaning anything")
+    return telemetry or stream is not None
 
-    # `prepare` runs EAGERLY in both modes — the same eager/jit boundary the
-    # legacy loop had (offline setup + init op-by-op, rounds compiled), so
-    # the scanned trajectory stays bit-identical to it; only Monte-Carlo
-    # sweeps trace `prepare` (under vmap over seeds/scenario scalars).
-    if timers is None:
-        ctx, carry, scan_xs = prepare(cfg.seed, cfg.snr_db)
-    else:
-        with timers.phase("prepare"):
-            ctx, carry, scan_xs = jax.block_until_ready(
-                prepare(cfg.seed, cfg.snr_db))
-    body = make_body(ctx)
 
-    tele = None
-    if mode == "scan":
-        fn = jax.jit(
-            lambda c, x: jax.lax.scan(body, c, x, unroll=_SCAN_UNROLL))
-        if checkpoint_dir is not None:
-            carry, out, _ = _run_scan_checkpointed(
-                fn, carry, scan_xs, T, checkpoint_dir, checkpoint_every,
-                resume=resume, resume_step=resume_step,
-                stop_after=stop_after, cfg=cfg, scenario=scenario,
-                strategy_name=get_strategy(cfg.strategy).name,
-                stream=stream)
-        elif timers is not None:
-            with timers.phase("trace_compile"):
-                fn = fn.lower(carry, scan_xs).compile()
-            timers.compiled(fn)
-            with timers.phase("execute"):
-                carry, out = jax.block_until_ready(fn(carry, scan_xs))
-        else:
-            carry, out = fn(carry, scan_xs)
-        if stream is not None:
-            # The tap's callbacks are asynchronous; make sure every round
-            # reached the host before the caller inspects the stream.
-            jax.block_until_ready(out)
-            jax.effects_barrier()
-        if telemetry:
-            loss, acc, tele = out
-        else:
-            loss, acc = out
-        consensus = carry["consensus"]
-    elif mode == "loop":
-        body_j = jax.jit(body)
-        loss_l, acc_l, tele_l = [], [], []
-        for t in range(T):
-            inp = jax.tree.map(lambda x: x[t], scan_xs)
-            if timers is not None:
-                with timers.phase("execute"):
-                    carry, out = jax.block_until_ready(body_j(carry, inp))
-            else:
-                carry, out = body_j(carry, inp)
-            if telemetry:
-                l, a, tl = out
-                tele_l.append(tl)
-            else:
-                l, a = out
-            loss_l.append(l)
-            acc_l.append(a)
-            if progress is not None:
-                progress(t + 1, float(l), float(a))
-        consensus = carry["consensus"]
-        loss, acc = jnp.stack(loss_l), jnp.stack(acc_l)
-        if telemetry:
-            tele = jax.tree.map(lambda *x: jnp.stack(x), *tele_l)
-    else:
-        raise ValueError(f"mode must be 'scan' or 'loop', got {mode!r}")
-
+def _trajectory_history(out, consensus, telemetry: bool) -> dict[str, Any]:
+    """A single trajectory's history from its stacked scan outputs
+    ``(loss, acc[, telemetry])`` and final consensus."""
+    loss, acc = out[0], out[1]
     history = {
         # rounds actually run: == T except when stop_after killed the
         # checkpointed run at a segment boundary (crash simulation).
@@ -735,8 +584,113 @@ def run_rounds(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
         "final_acc": acc[-1],
     }
     if telemetry:
-        history["telemetry"] = tele
+        history["telemetry"] = out[2]
     return history
+
+
+def run_rounds(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
+               topology: Topology, xs: jnp.ndarray, ys: jnp.ndarray,
+               x_test: jnp.ndarray, y_test: jnp.ndarray, cfg: FLConfig,
+               scenario: Optional[Scenario] = None,
+               topo_cfg: Optional[TopologyConfig] = None,
+               shard: Optional[str] = None,
+               mesh=None,
+               telemetry: bool = False,
+               timers=None,
+               checkpoint_dir: Optional[str] = None,
+               checkpoint_every: int = 0,
+               resume: bool = False,
+               resume_step: Optional[int] = None,
+               stop_after: Optional[int] = None,
+               stream=None) -> dict[str, Any]:
+    """Run one FL trajectory as one jitted scan (no per-round host sync);
+    returns history with on-device ``(T,)`` metric arrays.
+
+    ``shard="clients"``: distribute the stacked K-client axis over a
+    ``("clients",)`` mesh (`repro.sim.sharded.run_rounds_client_sharded`
+    — local training per rank, the per-cluster OTA sums riding a mesh
+    collective); static CWFL scenarios only.
+    ``telemetry=True`` (static flag, `repro.obs`): record a per-round
+    `RoundTelemetry` under ``history["telemetry"]`` — with the flag off
+    the traced computation is the untelemetered round.
+    ``timers``: an optional `repro.obs.profiling.PhaseTimers` splitting
+    the run into ``prepare``, ``trace_compile`` (AOT
+    ``lower().compile()``) and ``execute`` (to ``block_until_ready``)
+    wall phases; ``None`` keeps the default jit path untouched.
+
+    Checkpoint/resume (DESIGN.md §Faults): ``checkpoint_dir`` persists
+    the full scan carry + accumulated metrics every
+    ``checkpoint_every`` rounds (0 ⇒ one final checkpoint) via
+    `repro.checkpoint`, manifest-stamped with the run's config hash;
+    ``resume=True`` restores the latest step (or ``resume_step``) and
+    continues such that the interrupted+resumed history is BITWISE
+    identical to an uninterrupted run.  ``stop_after=r`` deliberately
+    exits at the first segment boundary ≥ r (crash simulation — CI's
+    chaos-smoke).
+
+    ``stream`` (STATIC; turns ``telemetry`` on): a
+    `repro.obs.stream.RoundStream` drained live from inside the scan via
+    an ordered `io_callback` — records arrive on the host in round order
+    while the trajectory runs, metrics stay bitwise, and with
+    ``stream=None`` the traced jaxpr is byte-identical to a
+    streaming-unaware build.  A stream whose monitor escalates alerts to
+    aborts requires ``checkpoint_dir`` (the abort IS a
+    checkpoint-then-stop).
+    """
+    scenario = scenario or Scenario()
+    if shard is not None:
+        if shard != "clients":
+            raise ValueError(
+                f"run_rounds shards the client axis only (shard='clients'); "
+                f"got {shard!r} — trajectory sharding (shard='mc') lives in "
+                "run_monte_carlo")
+        from repro.sim import sharded
+        return sharded.run_rounds_client_sharded(
+            init_fn, apply_fn, loss_fn, topology, xs, ys, x_test, y_test,
+            cfg, scenario=scenario, mesh=mesh, telemetry=telemetry,
+            checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
+            resume=resume, resume_step=resume_step, stop_after=stop_after,
+            stream=stream)
+    telemetry = _run_options(telemetry=telemetry, stream=stream,
+                             checkpoint_dir=checkpoint_dir, resume=resume,
+                             stop_after=stop_after, timers=timers)
+    prepare, make_body = _build(init_fn, apply_fn, loss_fn, topology, xs, ys,
+                                x_test, y_test, cfg, scenario, topo_cfg,
+                                telemetry=telemetry, stream=stream)
+
+    # `prepare` runs EAGERLY, so the strategy state is concrete and the
+    # round program closes over it as constants (`make_body`); only
+    # Monte-Carlo sweeps trace `prepare` (under vmap over seeds/scenario
+    # scalars).
+    if timers is None:
+        ctx, carry, scan_xs = prepare(cfg.seed, cfg.snr_db)
+    else:
+        with timers.phase("prepare"):
+            ctx, carry, scan_xs = jax.block_until_ready(
+                prepare(cfg.seed, cfg.snr_db))
+    body = make_body(ctx)
+
+    fn = jax.jit(lambda c, x: jax.lax.scan(body, c, x, unroll=_SCAN_UNROLL))
+    if checkpoint_dir is not None:
+        carry, out, _ = _run_scan_checkpointed(
+            fn, carry, scan_xs, cfg.rounds, checkpoint_dir, checkpoint_every,
+            resume=resume, resume_step=resume_step, stop_after=stop_after,
+            cfg=cfg, scenario=scenario,
+            strategy_name=get_strategy(cfg.strategy).name, stream=stream)
+    elif timers is not None:
+        with timers.phase("trace_compile"):
+            fn = fn.lower(carry, scan_xs).compile()
+        timers.compiled(fn)
+        with timers.phase("execute"):
+            carry, out = jax.block_until_ready(fn(carry, scan_xs))
+    else:
+        carry, out = fn(carry, scan_xs)
+    if stream is not None:
+        # The tap's callbacks are asynchronous; make sure every round
+        # reached the host before the caller inspects the stream.
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+    return _trajectory_history(out, carry["consensus"], telemetry)
 
 
 def run_monte_carlo(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
@@ -766,7 +720,7 @@ def run_monte_carlo(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
     under ``history["telemetry"]`` (leading axes (S,[G,]T)).  ``timers``:
     optional `PhaseTimers` — see `run_rounds`.
 
-    ``stream`` (STATIC, needs ``telemetry=True``): per-round records for
+    ``stream`` (STATIC; turns ``telemetry`` on): per-round records for
     every trajectory in the sweep.  The trajectory is vmapped, so the
     tap sits AFTER each trajectory's scan (`stream_trajectory_tap` on
     the round-stacked outputs — in-body taps either cannot batch
@@ -780,9 +734,7 @@ def run_monte_carlo(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
     scenario = scenario or Scenario()
     if snr_grid is None and scenario.snr_grid:
         snr_grid = scenario.snr_grid
-    if stream is not None and not telemetry:
-        raise ValueError(
-            "stream= drains RoundTelemetry live and needs telemetry=True")
+    telemetry = _run_options(telemetry=telemetry, stream=stream)
     prepare, make_body = _build(init_fn, apply_fn, loss_fn, topology, xs, ys,
                                 x_test, y_test, cfg, scenario, topo_cfg,
                                 telemetry=telemetry)
@@ -808,7 +760,6 @@ def run_monte_carlo(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
             return jax.block_until_ready(fn(*a))
 
     seed_arr = jnp.asarray(cfg.seed + np.arange(seeds))
-    tele = None
     if shard is not None:
         if shard != "mc":
             raise ValueError(
@@ -819,25 +770,15 @@ def run_monte_carlo(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
         out = sharded.monte_carlo_sharded(
             traj, seed_arr, snr_grid, cfg.snr_db, cfg.rounds, mesh=mesh,
             telemetry=telemetry, stream=stream)
-        if telemetry:
-            loss, acc, grid, tele = out
-        else:
-            loss, acc, grid = out
+        grid, out = out[2], out[:2] + out[3:]
     elif snr_grid is None:
-        out = _run(jax.vmap(traj, in_axes=(0, None)), seed_arr, cfg.snr_db)
         grid = None
-        if telemetry:
-            loss, acc, tele = out
-        else:
-            loss, acc = out
+        out = _run(jax.vmap(traj, in_axes=(0, None)), seed_arr, cfg.snr_db)
     else:
         grid = jnp.asarray(snr_grid, jnp.float32)
         out = _run(jax.vmap(jax.vmap(traj, in_axes=(None, 0)),
                             in_axes=(0, None)), seed_arr, grid)
-        if telemetry:
-            loss, acc, tele = out
-        else:
-            loss, acc = out
+    loss, acc = out[0], out[1]
     if stream is not None:
         jax.block_until_ready(loss)
         jax.effects_barrier()
@@ -849,5 +790,5 @@ def run_monte_carlo(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
         "snr_grid": grid,
     }
     if telemetry:
-        history["telemetry"] = tele
+        history["telemetry"] = out[2]
     return history

@@ -9,7 +9,7 @@ Registry (see DESIGN.md §Sim for the math behind each knob):
 
 * ``paper-static``    — the paper's §V protocol verbatim: stationary
   channel, full participation.  The engine's trajectory under this
-  scenario is bit-identical to the pre-engine `run_federated` loop.
+  scenario is pinned bit for bit by the goldens (``tests/goldens``).
 * ``mobile-fading``   — random-waypoint mobility + Gauss-Markov fading +
   log-normal shadowing + imperfect CSI (cf. arXiv 2207.09232's mobile
   hierarchical setting).

@@ -46,12 +46,14 @@ from repro.dist.sharding_rules import client_specs, trajectory_specs
 from repro.kernels.ota_aggregate import SYNC_PRECISION
 from repro.launch.mesh import make_client_mesh, make_mc_mesh
 from repro.models.small import accuracy as _accuracy
-from repro.obs.telemetry import RoundTelemetry, init_ledger, per_client_dim
-from repro.sim.engine import (_SCAN_UNROLL, client_rows,
-                              make_round_local_runner)
+from repro.obs.telemetry import RoundTelemetry, per_client_dim
+from repro.sim.engine import (_SCAN_UNROLL, _build, _run_options,
+                              _run_scan_checkpointed, _trajectory_history,
+                              client_rows, make_round_local_runner)
 from repro.sim.scenarios import Scenario
 from repro.strategies import get_strategy
 from repro.training.federated import FLConfig
+from repro.training.local import frozen_base
 
 
 # ---------------------------------------------------------------------------
@@ -311,9 +313,13 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
     Static CWFL scenarios only — the per-round state rebuilds of dynamic
     scenarios replicate fine, but masking/re-clustering haven't been
     taught the sharded sync yet (raise rather than silently diverge).
-    The carry and key schedule come from `engine._build`'s own eager
-    ``prepare`` (not a copy), so they track the unsharded path by
-    construction; metrics agree to psum-reassociation tolerance.
+    Models over a frozen base (`repro.training.local.FrozenBase`) are
+    refused too: this round body closes over the raw ``loss_fn``, which
+    would embed the base in the program as a constant.  The carry, the
+    key schedule and the round tags come from `engine._build`'s own
+    eager ``prepare`` (not a copy), so they track the unsharded path by
+    construction; metrics agree to psum-reassociation tolerance.  The
+    round body itself is this module's own (DESIGN.md §Sharded-MC).
 
     ``telemetry=True`` (static flag) emits ``history["telemetry"]`` with
     the same `RoundTelemetry` fields as the unsharded engine: per-cluster
@@ -322,16 +328,17 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
     replicated internals (`_client_sharded_sync`'s extras).
 
     ``checkpoint_dir``/``checkpoint_every``/``resume``/``resume_step``/
-    ``stop_after``: chunked checkpoint/resume with the same contract as
-    `engine.run_rounds` — the scan is split into segments and the full
-    carry (sharded param/opt stacks gathered to host, consensus, ledger)
-    is persisted at each boundary, manifest-stamped (the manifest's
+    ``stop_after``: the jitted ``shard_map`` trajectory has the
+    ``fn(carry, scan_xs) -> (carry, out)`` form `engine.run_rounds`
+    scans, so the engine's own segment driver
+    (`engine._run_scan_checkpointed`) checkpoints it: the full carry
+    (sharded param/opt stacks gathered to host, consensus, ledger) is
+    persisted at each boundary, manifest-stamped (the manifest's
     strategy field carries an ``@clients`` suffix so sharded and
     unsharded checkpoints — equal only to psum-reassociation ulps —
-    can never be spliced).  With checkpointing off the traced
-    computation is byte-identical to before (static-flag discipline).
+    can never be spliced).
 
-    ``stream`` (STATIC, needs ``telemetry=True``): a
+    ``stream`` (STATIC; turns ``telemetry`` on): a
     `repro.obs.stream.RoundStream` tapped from inside the shard_map'd
     scan body — every rank fires the callback on its replicated round
     values and passes ``lax.axis_index("clients")`` along, and the host
@@ -341,28 +348,19 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
     aborts XLA's sharding propagation on this toolchain); each record's
     absolute round tag carries the ordering instead.
     """
-    from repro.sim.engine import _build, checkpoint_manifest
-
     scenario = scenario or Scenario()
-    ckpt = checkpoint_dir is not None
-    streaming = stream is not None
-    if not ckpt and (resume or stop_after is not None):
-        raise ValueError(
-            "resume/stop_after need checkpoint_dir — there is nothing to "
-            "restore from or checkpoint into")
-    if streaming:
-        if not telemetry:
-            raise ValueError(
-                "stream= drains RoundTelemetry live and needs "
-                "telemetry=True")
-        if stream.escalates and not ckpt:
-            raise ValueError(
-                "abort-on-alert escalates via the checkpoint machinery "
-                "(checkpoint-then-stop, resumable); pass checkpoint_dir")
+    telemetry = _run_options(telemetry=telemetry, stream=stream,
+                             checkpoint_dir=checkpoint_dir, resume=resume,
+                             stop_after=stop_after)
     if not scenario.is_static:
         raise NotImplementedError(
             "shard='clients' supports static scenarios only (dynamic "
             "masking/re-clustering haven't been taught the sharded sync)")
+    if frozen_base(loss_fn) is not None:
+        raise NotImplementedError(
+            "shard='clients' does not take a model over a frozen base "
+            "(FrozenBase): its round body would embed the base in the "
+            "program as a constant")
     strategy = get_strategy(cfg.strategy)
     if not strategy.supports_client_sharding:
         raise NotImplementedError(
@@ -382,16 +380,15 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
         raise ValueError(
             f"K={K} clients must divide over the {n_dev}-way clients axis")
     kl = K // n_dev
-    T = cfg.rounds
 
-    # EAGER prepare — the engine's own (bit-identity-protected) setup and
-    # PRNG schedule; a static scenario's ctx IS the strategy state.
+    # EAGER prepare — the engine's own setup and PRNG schedule; a static
+    # scenario's ctx IS the strategy state.  The carry holds stacked,
+    # opt, consensus (+ the "obs" ledger with telemetry); the scan inputs
+    # hold the round keys (+ the absolute round tags with a stream).
     prepare, _ = _build(init_fn, apply_fn, loss_fn, topology, xs, ys,
-                        x_test, y_test, cfg, scenario, None)
+                        x_test, y_test, cfg, scenario, None,
+                        telemetry=telemetry, stream=stream)
     state0, carry0, scan_xs = prepare(cfg.seed, cfg.snr_db)
-    stacked, opt_state = carry0["stacked"], carry0["opt"]
-    params0 = carry0["consensus"]
-    round_keys = scan_xs["rkey"]
 
     xs, sample_shape = client_rows(xs)
     _, local_run = make_round_local_runner(loss_fn, cfg, n_k, sample_shape)
@@ -404,29 +401,16 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
         strategy.channel_uses(K, num_clusters=cfg.num_clusters),
         jnp.float32)
 
-    def traj(stacked0, opt0, cons0, xs_l, ys_l, rkeys, *extra):
-        # extra = ([sts] when streaming) + ([ledger0] on the checkpointed
-        # telemetry path) — absolute round indices for the stream tap
-        # (sliced alongside rkeys by the segment driver, so a resumed
-        # stream keeps absolute rounds) and the cumulative channel-use
-        # ledger that must survive a resume.
-        extra = list(extra)
-        sts = extra.pop(0) if streaming else None
+    def traj(carry, seg, xs_l, ys_l):
         r = jax.lax.axis_index("clients")
 
         def body(carry, inp):
-            if streaming:
-                rkey, st_t = inp
-            else:
-                rkey, st_t = inp, None
-            if telemetry:
-                st, opt, _, ledger = carry
-            else:
-                st, opt, _ = carry
-            k_local, k_agg = jax.random.split(rkey)
+            carry = dict(carry)
+            k_local, k_agg = jax.random.split(inp["rkey"])
             client_keys = jax.random.split(k_local, K)   # global schedule
             ck = jax.lax.dynamic_slice_in_dim(client_keys, r * kl, kl)
-            st, opt, losses = jax.vmap(local_run)(st, opt, xs_l, ys_l, ck)
+            st, opt, losses = jax.vmap(local_run)(
+                carry["stacked"], carry["opt"], xs_l, ys_l, ck)
             if telemetry:
                 new, consensus, extras = _client_sharded_sync(
                     st, state0, k_agg, "clients", with_telemetry=True)
@@ -436,8 +420,9 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
             loss = jax.lax.psum(jnp.sum(losses), "clients") / K
             logits = apply_fn(consensus, x_ev)
             acc = _accuracy(logits, y_ev)
+            carry.update(stacked=new, opt=opt, consensus=consensus)
             if not telemetry:
-                return (new, opt, consensus), (loss, acc)
+                return carry, (loss, acc)
             mem_loc = jax.lax.dynamic_slice_in_dim(membership, r * kl, kl,
                                                    axis=1)     # (C, K')
             # Fresh full-shard losses for telemetry — reading the
@@ -449,18 +434,19 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
             cluster_loss = jax.lax.psum(mem_loc @ tele_losses,
                                         "clients") / counts
             d = per_client_dim(st)
-            new_ledger = {"uses": ledger["uses"] + uses,
-                          "symbols": ledger["symbols"] + uses * d}
+            ledger = carry["obs"]
+            carry["obs"] = {"uses": ledger["uses"] + uses,
+                            "symbols": ledger["symbols"] + uses * d}
             tele = RoundTelemetry(
                 cluster_loss=cluster_loss,
                 participants=jnp.asarray(K, jnp.float32),
                 consensus_drift=extras.pop("consensus_drift"),
                 channel_uses=uses,
-                cum_channel_uses=new_ledger["uses"],
-                cum_symbols=new_ledger["symbols"],
+                cum_channel_uses=carry["obs"]["uses"],
+                cum_symbols=carry["obs"]["symbols"],
                 reclustered=jnp.zeros((), jnp.float32),
                 extras=extras)
-            if streaming:
+            if stream is not None:
                 # In-body tap on replicated round values; the axis index
                 # rides the payload and the host drops ranks != 0.
                 # UNORDERED: an ordered effect token inside a jitted
@@ -469,191 +455,50 @@ def run_rounds_client_sharded(init_fn, apply_fn, loss_fn, topology,
                 # the absolute round tag in the payload carries the
                 # ordering instead, and consumers sort by it.
                 from repro.obs.stream import stream_tap
-                stream_tap(stream, t=st_t, seed=cfg.seed, snr=cfg.snr_db,
-                           loss=loss, acc=acc, telemetry=tele, rank=r,
-                           ordered=False)
-            return (new, opt, consensus, new_ledger), (loss, acc, tele)
+                tags = inp["stream"]
+                stream_tap(stream, t=tags["t"], seed=tags["seed"],
+                           snr=tags["snr"], loss=loss, acc=acc,
+                           telemetry=tele, rank=r, ordered=False)
+            return carry, (loss, acc, tele)
 
-        xs_scan = (rkeys, sts) if streaming else rkeys
-        if telemetry:
-            ledger0 = extra.pop(0) if ckpt else init_ledger()
-            (st_f, opt_f, final, ledger_f), out = jax.lax.scan(
-                body, (stacked0, opt0, cons0, ledger0), xs_scan,
-                unroll=_SCAN_UNROLL)
-            loss, acc, tele = out
-            if ckpt:
-                return loss, acc, final, tele, st_f, opt_f, ledger_f
-            return loss, acc, final, tele
-        (st_f, opt_f, final), (loss, acc) = jax.lax.scan(
-            body, (stacked0, opt0, cons0), xs_scan, unroll=_SCAN_UNROLL)
-        if ckpt:
-            return loss, acc, final, st_f, opt_f
-        return loss, acc, final
+        return jax.lax.scan(body, carry, seg, unroll=_SCAN_UNROLL)
 
     # Specs come from the dist rules layer: leading K over "clients" for
     # every stacked leaf, replication for everything per-rank identical.
     k_spec = lambda tree: client_specs(jax.eval_shape(lambda t: t, tree),
                                        mesh)
     rep = lambda tree: jax.tree.map(lambda _: P(), tree)
-    ledger0 = init_ledger() if telemetry else None
-    sts_full = jnp.arange(T, dtype=jnp.int32) if streaming else None
-    in_specs: tuple = (k_spec(stacked), k_spec(opt_state), rep(params0),
-                       P("clients"), P("clients"), P())
-    if streaming:
-        in_specs = in_specs + (P(),)          # sts: replicated round tags
-    out_specs: tuple = (P(), P(), rep(params0))
+    carry_spec = rep(carry0)
+    carry_spec.update(stacked=k_spec(carry0["stacked"]),
+                      opt=k_spec(carry0["opt"]))
+    out_spec: tuple = (P(), P())
     if telemetry:
         # Every telemetry value is psum-replicated or a rank-constant —
         # all-P() specs, keyed off the known extras layout.
-        tele_spec = RoundTelemetry(
+        out_spec = out_spec + (RoundTelemetry(
             cluster_loss=P(), participants=P(), consensus_drift=P(),
             channel_uses=P(), cum_channel_uses=P(), cum_symbols=P(),
             reclustered=P(),
-            extras={k: P() for k in _CLIENT_TELE_EXTRAS})
-        out_specs = out_specs + (tele_spec,)
-    if ckpt:
-        out_specs = out_specs + (k_spec(stacked), k_spec(opt_state))
-        if telemetry:
-            in_specs = in_specs + (rep(ledger0),)
-            out_specs = out_specs + (rep(ledger0),)
-    f = shard_map(
+            extras={k: P() for k in _CLIENT_TELE_EXTRAS}),)
+    fj = jax.jit(shard_map(
         traj, mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_vma=False)   # scan+psum bodies defeat the replication checker
-    fj = jax.jit(f)
+        in_specs=(carry_spec, rep(scan_xs), P("clients"), P("clients")),
+        out_specs=(carry_spec, out_spec),
+        check_vma=False))  # scan+psum bodies defeat the replication checker
+    fn = lambda carry, seg: fj(carry, seg, xs, ys)
 
-    tele = None
-    if not ckpt:
-        args = (stacked, opt_state, params0, xs, ys, round_keys)
-        if streaming:
-            args = args + (sts_full,)
-        out = fj(*args)
-        if streaming:
-            jax.block_until_ready(out)
-            jax.effects_barrier()
-        if telemetry:
-            loss, acc, consensus, tele = out
-        else:
-            loss, acc, consensus = out
+    if checkpoint_dir is not None:
+        # "@clients" keys the manifest hash: sharded and unsharded
+        # histories agree only to psum-reassociation ulps — never splice
+        # them.
+        carry, out, _ = _run_scan_checkpointed(
+            fn, carry0, scan_xs, cfg.rounds, checkpoint_dir,
+            checkpoint_every, resume=resume, resume_step=resume_step,
+            stop_after=stop_after, cfg=cfg, scenario=scenario,
+            strategy_name=strategy.name + "@clients", stream=stream)
     else:
-        loss, acc, consensus, tele = _client_sharded_checkpointed(
-            fj, stacked, opt_state, params0, ledger0, xs, ys, round_keys,
-            T, cfg, scenario, strategy, telemetry=telemetry,
-            checkpoint_dir=checkpoint_dir, checkpoint_every=checkpoint_every,
-            resume=resume, resume_step=resume_step, stop_after=stop_after,
-            manifest_fn=checkpoint_manifest, stream=stream,
-            sts_full=sts_full)
-
-    history = {
-        "round": np.arange(1, int(loss.shape[0]) + 1),
-        "train_loss": loss,
-        "test_acc": acc,
-        "final_params": consensus,
-        "avg_acc": jnp.mean(acc),
-        "final_acc": acc[-1],
-    }
-    if telemetry:
-        history["telemetry"] = tele
-    return history
-
-
-def _client_sharded_checkpointed(fj, stacked, opt_state, params0, ledger0,
-                                 xs, ys, round_keys, T: int, cfg, scenario,
-                                 strategy, *, telemetry: bool,
-                                 checkpoint_dir, checkpoint_every: int,
-                                 resume: bool, resume_step, stop_after,
-                                 manifest_fn, stream=None, sts_full=None):
-    """Segment driver for the checkpointed client-sharded trajectory —
-    the `engine._run_scan_checkpointed` contract on the shard_map path:
-    run ``checkpoint_every``-round chunks, persist the full carry +
-    accumulated metrics at each boundary, restore and continue on
-    ``resume`` (bitwise — the chunked scan is the same per-round body).
-    """
-    from pathlib import Path
-
-    from repro.checkpoint import (latest_step, load_checkpoint,
-                                  save_checkpoint)
-
-    directory = Path(checkpoint_dir)
-    every = (T if checkpoint_every is None or int(checkpoint_every) <= 0
-             else min(int(checkpoint_every), T))
-    # "@clients" keys the manifest hash: sharded and unsharded histories
-    # agree only to psum-reassociation ulps — never splice them.
-    manifest_fn(directory, cfg, scenario, strategy.name + "@clients",
-                resume)
-
-    streaming = stream is not None
-
-    def call(st, opt, cons, ld, keys, sts_seg):
-        args = (st, opt, cons, xs, ys, keys)
-        if streaming:
-            args = args + (sts_seg,)
-        if telemetry:
-            args = args + (ld,)
-        return fj(*args)
-
-    def out_template(n):
-        # Abstract-evaluate the jitted shard_map fn for an n-round chunk:
-        # the (loss, acc[, telemetry]) accumulator template for resume.
-        args = (stacked, opt_state, params0, xs, ys, round_keys[:n])
-        if streaming:
-            args = args + (sts_full[:n],)
-        if telemetry:
-            args = args + (ledger0,)
-        shapes = jax.eval_shape(fj, *args)
-        sub = ((shapes[0], shapes[1], shapes[3]) if telemetry
-               else (shapes[0], shapes[1]))
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), sub)
-
-    st, opt, cons, ld = stacked, opt_state, params0, ledger0
-    start, acc_out = 0, None
-    if resume:
-        step = (resume_step if resume_step is not None
-                else latest_step(directory))
-        if step is None:
-            raise FileNotFoundError(
-                f"resume: no checkpoint steps in {directory}")
-        if not 0 < step <= T:
-            raise ValueError(
-                f"resume: checkpoint step {step} outside this run's "
-                f"1..{T} round range")
-        template = {"stacked": stacked, "opt": opt_state,
-                    "consensus": params0, "out": out_template(step)}
-        if telemetry:
-            template["ledger"] = ledger0
-        payload = load_checkpoint(directory, template, step=step)
-        st, opt, cons = (payload["stacked"], payload["opt"],
-                         payload["consensus"])
-        ld = payload.get("ledger", ledger0)
-        acc_out, start = payload["out"], int(step)
-
-    pos = start
-    while pos < T:
-        end = min(pos + every, T)
-        res = call(st, opt, cons, ld, round_keys[pos:end],
-                   sts_full[pos:end] if streaming else None)
-        if telemetry:
-            loss_s, acc_s, cons, tele_s, st, opt, ld = res
-            seg = (loss_s, acc_s, tele_s)
-        else:
-            loss_s, acc_s, cons, st, opt = res
-            seg = (loss_s, acc_s)
-        acc_out = seg if acc_out is None else jax.tree.map(
-            lambda a, b: jnp.concatenate([a, b], axis=0), acc_out, seg)
-        pos = end
-        payload = {"stacked": st, "opt": opt, "consensus": cons,
-                   "out": acc_out}
-        if telemetry:
-            payload["ledger"] = ld
-        save_checkpoint(directory, pos, payload)
-        if stop_after is not None and pos >= int(stop_after) and pos < T:
-            break
-        if streaming:
-            jax.effects_barrier()   # drain the segment before polling
-            if stream.should_abort and pos < T:
-                break
-
-    if telemetry:
-        return acc_out[0], acc_out[1], cons, acc_out[2]
-    return acc_out[0], acc_out[1], cons, None
+        carry, out = fn(carry0, scan_xs)
+    if stream is not None:
+        jax.block_until_ready(out)
+        jax.effects_barrier()
+    return _trajectory_history(out, carry["consensus"], telemetry)

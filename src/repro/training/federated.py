@@ -1,8 +1,8 @@
 """The paper-protocol front door (`run_federated` + `FLConfig`).
 
 One round = E local epochs at every client in parallel (vmap) followed by one
-synchronization (t ∈ H) under the selected aggregation strategy.  The round
-loop itself lives in :mod:`repro.sim.engine` (a `lax.scan` over rounds,
+synchronization (t ∈ H) under the selected aggregation strategy.  The
+rounds run in :mod:`repro.sim.engine` (a `lax.scan` over rounds,
 vmap-able over seeds/scenario scalars); `run_federated` is the stable
 paper-protocol entry point wrapping it.
 
@@ -83,34 +83,28 @@ class FLConfig:
 def run_federated(init_fn: Callable, apply_fn: Callable, loss_fn: Callable,
                   topology: Topology, xs: jnp.ndarray, ys: jnp.ndarray,
                   x_test: jnp.ndarray, y_test: jnp.ndarray,
-                  cfg: FLConfig, progress: Optional[Callable] = None,
-                  scenario=None, topo_cfg=None) -> dict[str, Any]:
+                  cfg: FLConfig, scenario=None,
+                  topo_cfg=None) -> dict[str, Any]:
     """Run FL; returns history dict with per-round test accuracy/loss.
 
     ``xs, ys``: stacked client shards (K, N_k, ...).
 
-    Compatibility wrapper over the scenario engine
-    (:func:`repro.sim.engine.run_rounds`).  With the default (static)
-    scenario the scanned engine's history is bit-identical to the legacy
-    per-round Python loop this function used to implement; when a live
-    ``progress`` callback is given the engine's loop mode (same numbers,
-    per-round host sync) is used so the callback fires as rounds finish.
+    A plain wrapper over the scanned engine
+    (:func:`repro.sim.engine.run_rounds`) that returns host floats.
     ``scenario``/``topo_cfg`` opt into `repro.sim` dynamics (time-varying
     channels, participation masks, re-clustering).
     """
     from repro.sim.engine import run_rounds  # deferred: sim imports us
 
-    mode = "loop" if progress is not None else "scan"
     h = run_rounds(init_fn, apply_fn, loss_fn, topology, xs, ys,
                    x_test, y_test, cfg, scenario=scenario,
-                   topo_cfg=topo_cfg, mode=mode, progress=progress)
-
-    history = {
+                   topo_cfg=topo_cfg)
+    test_acc = [float(x) for x in np.asarray(h["test_acc"])]
+    return {
         "round": [int(r) for r in h["round"]],
         "train_loss": [float(x) for x in np.asarray(h["train_loss"])],
-        "test_acc": [float(x) for x in np.asarray(h["test_acc"])],
+        "test_acc": test_acc,
+        "final_params": h["final_params"],
+        "avg_acc": float(h["avg_acc"]),
+        "final_acc": test_acc[-1],
     }
-    history["final_params"] = h["final_params"]
-    history["avg_acc"] = float(h["avg_acc"])
-    history["final_acc"] = history["test_acc"][-1]
-    return history

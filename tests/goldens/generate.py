@@ -83,8 +83,8 @@ def main() -> None:
             "jax": jax.__version__,
             "backend": jax.default_backend(),
             # CPU reductions tile by the host-platform device/thread
-            # config, so the exact bits are pinned to the CI layout
-            # (8 fake CPU devices); other configs get the ulp bound.
+            # config, so the exact bits are pinned to the generating
+            # layout; other configs get the ulp bound.
             "devices": len(jax.devices()),
         },
         "strategies": {},

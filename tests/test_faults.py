@@ -317,8 +317,6 @@ def test_checkpoint_api_validation(wl, tmp_path):
         _hist(wl, "cwfl", resume=True)
     with pytest.raises(ValueError, match="checkpoint_dir"):
         _hist(wl, "cwfl", stop_after=2)
-    with pytest.raises(ValueError, match="loop"):
-        _hist(wl, "cwfl", checkpoint_dir=tmp_path, mode="loop")
 
 
 @pytest.mark.slow

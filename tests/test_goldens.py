@@ -11,9 +11,9 @@ without a pre-refactor checkout.
 Regenerate intentionally with ``PYTHONPATH=src python
 tests/goldens/generate.py`` — a diff of the ``*_repr`` fields documents
 the drift.  The exact bits are pinned to the config that generated them
-(CPU backend, 8 fake devices — CI's tier-1 layout): XLA CPU tiles
-reductions by the device/thread config, which legally re-associates a
-mean by 1 ulp.  Under any other config the test enforces a 2-ulp bound
+(backend, device count and jax version, recorded in the fixture's
+``protocol``): XLA CPU tiles reductions by the device/thread config,
+which legally re-associates a mean by 1 ulp.  Under any other config the test enforces a 2-ulp bound
 instead — still tight enough that any real regression (wrong key
 schedule, changed math) fails loudly.
 """

@@ -178,19 +178,6 @@ def test_monte_carlo_telemetry_batches(wl):
         np.full(S, float(uses_per_round("cwfl", K, 3)) * T))
 
 
-def test_loop_mode_telemetry_matches_scan(wl):
-    """mode='loop' stacks per-round telemetry into the same pytree the
-    scan emits (same shapes; histories bit-identical as ever)."""
-    h_scan = _run(wl, _cfg("cwfl"), telemetry=True)
-    h_loop = _run(wl, _cfg("cwfl"), telemetry=True, mode="loop")
-    assert bool(jnp.array_equal(h_scan["train_loss"], h_loop["train_loss"]))
-    assert (jax.tree.structure(h_scan["telemetry"])
-            == jax.tree.structure(h_loop["telemetry"]))
-    for a, b in zip(jax.tree.leaves(h_scan["telemetry"]),
-                    jax.tree.leaves(h_loop["telemetry"])):
-        assert a.shape == b.shape
-
-
 # ---------------------------------------------------------------------------
 # Channel-use ledger: ONE source of truth for the §IV cost claim.
 # ---------------------------------------------------------------------------

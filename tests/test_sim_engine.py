@@ -1,9 +1,8 @@
-"""Scan-engine equivalence + scenario behaviour (repro.sim.engine).
+"""Scan-engine behaviour (repro.sim.engine).
 
-The heart of the subsystem's correctness story: the scanned trajectory
-under the ``paper-static`` scenario must reproduce the legacy per-round
-loop (and hence the pre-refactor `run_federated`) BIT-FOR-BIT, and the
+`run_federated` must be an exact wrapper of the scanned engine, and the
 participation-mask machinery must be exactly inert at an all-ones mask.
+The static trajectory's bits are pinned by tests/test_goldens.py.
 """
 import jax
 import jax.numpy as jnp
@@ -38,54 +37,16 @@ def _hist_equal(h1, h2):
             and bool(jnp.array_equal(h1["test_acc"], h2["test_acc"])))
 
 
-# ---------------------------------------------------------------------------
-# Satellite: static-scenario scan == legacy loop, bit-for-bit.
-# ---------------------------------------------------------------------------
-
-def test_scan_equals_loop_bitwise_cwfl(setup):
-    """Tiny MLP, odd round count (exercises the unroll=2 remainder): the
-    single-jit scanned trajectory reproduces the per-round-jit loop — the
-    pre-refactor `run_federated` structure — exactly."""
-    init, apply, loss, topo, xs, ys, xte, yte = setup
-    cfg = FLConfig(strategy="cwfl", rounds=5, snr_db=40.0,
-                   eval_samples=256, seed=3)
-    h_scan = run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg,
-                        mode="scan")
-    h_loop = run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg,
-                        mode="loop")
-    assert _hist_equal(h_scan, h_loop)
-    for a, b in zip(jax.tree.leaves(h_scan["final_params"]),
-                    jax.tree.leaves(h_loop["final_params"])):
-        assert bool(jnp.array_equal(a, b))
-
-
-@pytest.mark.parametrize("strategy", ["cotaf", "fedavg", "decentralized"])
-@pytest.mark.slow
-def test_scan_equals_loop_bitwise_baselines(setup, strategy):
-    init, apply, loss, topo, xs, ys, xte, yte = setup
-    cfg = FLConfig(strategy=strategy, rounds=3, snr_db=40.0,
-                   eval_samples=256, seed=3)
-    h_scan = run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg,
-                        mode="scan")
-    h_loop = run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg,
-                        mode="loop")
-    assert _hist_equal(h_scan, h_loop)
-
-
 def test_run_federated_wraps_engine_exactly(setup):
-    """The compatibility wrapper's float lists match the engine arrays
-    (and the progress-callback loop path matches the scan path)."""
+    """The wrapper's float lists match the engine arrays."""
     init, apply, loss, topo, xs, ys, xte, yte = setup
     cfg = FLConfig(strategy="cwfl", rounds=4, snr_db=40.0,
                    eval_samples=256, seed=1)
     h_eng = run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg)
-    seen = []
-    h_wrap = run_federated(init, apply, loss, topo, xs, ys, xte, yte, cfg,
-                           progress=lambda r, l, a: seen.append((r, l, a)))
+    h_wrap = run_federated(init, apply, loss, topo, xs, ys, xte, yte, cfg)
     assert h_wrap["train_loss"] == [float(x) for x in h_eng["train_loss"]]
     assert h_wrap["test_acc"] == [float(x) for x in h_eng["test_acc"]]
     assert h_wrap["round"] == list(range(1, 5))
-    assert len(seen) == 4 and seen[0][0] == 1
     assert h_wrap["avg_acc"] == pytest.approx(float(h_eng["avg_acc"]))
 
 

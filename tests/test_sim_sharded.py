@@ -31,6 +31,7 @@ from repro.sim.engine import _build, make_trajectory_fn
 from repro.sim.scenarios import Scenario
 from repro.sim.sharded import monte_carlo_sharded
 from repro.training import FLConfig
+from repro.training.local import FrozenBase
 
 K = 8
 TCFG = TopologyConfig(num_clients=K, num_hotspots=3)
@@ -206,11 +207,12 @@ def test_client_sharded_guards(setup):
     with pytest.raises(NotImplementedError, match="static"):
         run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg,
                    scenario=sc, shard="clients")
-    # live-progress / loop mode would be silently dead on the sharded
-    # path — must refuse loudly instead
-    with pytest.raises(ValueError, match="progress"):
-        run_rounds(init, apply, loss, topo, xs, ys, xte, yte, cfg,
-                   shard="clients", progress=lambda *a: None)
+    # a frozen base would be embedded in the sharded round program as a
+    # constant — must refuse loudly instead
+    frozen = FrozenBase(lambda p, x, y, base: loss(p, x, y), base={"w": jnp.zeros(2)})
+    with pytest.raises(NotImplementedError, match="frozen base"):
+        run_rounds(init, apply, frozen, topo, xs, ys, xte, yte, cfg,
+                   shard="clients")
 
 
 # ---------------------------------------------------------------------------

@@ -166,8 +166,16 @@ def test_stream_matches_posthoc_bitwise(wl, strategy):
 
 
 def test_stream_requires_telemetry(wl):
-    with pytest.raises(ValueError):
-        _run(wl, _cfg("cwfl"), stream=RoundStream([MemorySink()]))
+    """A stream drains the round telemetry, so passing one without
+    ``telemetry=True`` still yields it — bitwise the explicit run's."""
+    ref = _run(wl, _cfg("cwfl"), telemetry=True)
+    stream = RoundStream([MemorySink()])
+    h = _run(wl, _cfg("cwfl"), stream=stream)
+    assert len(stream.records()) == 2
+    for key in ("train_loss", "test_acc"):
+        assert np.array_equal(np.asarray(h[key]), np.asarray(ref[key]))
+    _assert_tree_bitwise(_np_tree(h["telemetry"]),
+                         _np_tree(ref["telemetry"]), "telemetry")
 
 
 def test_mc_vmap_stream_bitwise(wl):
